@@ -16,9 +16,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import Backend, DaismConfig, Variant, daism_matmul
+from repro.roofline.analysis import chip_peaks
 
+# the derived columns model this chip (estimates, not measurements)
+TARGET_KIND = "TPU v5 lite"
 VPU_INT32_OPS = 4e12     # ~per chip
-MXU_FLOPS = 197e12
+MXU_FLOPS = chip_peaks(TARGET_KIND).bf16_flops
 # int32 VPU op-equivalents per MAC, per backend, from each backend's actual
 # op mix (previously one shared constant made the derived column identical
 # for all three approximate backends — it distinguished nothing):

@@ -18,12 +18,14 @@ configurations and backs the repo's serving claims:
   self-speculative decoding (cheap-draft k=3 + one exact batched verify):
   token-identical to plain, > 1.5 tokens per verify step, accept rate per
   draft tier.
-* ``multi_device`` — subprocess children at 1 vs 4 virtual CPU devices,
-  equal total KV memory: the 4-way tensor-parallel engine (sharded params,
-  KV pages, and decode step) emits identical tokens — also with
-  preemption + speculative decoding stacked on top. The children run f32
-  compute so the row-parallel psum reorder (~1e-6) stays far below toy
-  logit gaps.
+* ``multi_device`` (CPU only) — subprocess children at 1 vs 4 virtual
+  CPU devices, equal total KV memory: the 4-way tensor-parallel engine
+  (sharded params, KV pages, and decode step) emits identical tokens —
+  also with preemption + speculative decoding stacked on top. The children
+  run f32 compute so the row-parallel psum reorder (~1e-6) stays far below
+  toy logit gaps. On a TPU host the suite refuses to run: a child cannot
+  open the chip its parent holds (``chip_smoke.py --four-chips`` covers
+  sharded serving on chips).
 
 Wall times on this CPU container measure *relative* overhead (the jnp
 bit-op backend is reference semantics, not a fast kernel); deployment
@@ -92,6 +94,7 @@ def _multidevice_child(devices: int, spec: bool = False) -> None:
     import jax
 
     from repro.configs import get_config
+    from repro.launch.mesh import make_mesh
     from repro.models.registry import build_model
     from repro.serve import EngineConfig, ServeEngine, poisson_requests
 
@@ -100,7 +103,7 @@ def _multidevice_child(devices: int, spec: bool = False) -> None:
         compute_dtype="float32", param_dtype="float32")
     model = build_model(cfg)
     params, _ = model.init(jax.random.PRNGKey(0))
-    mesh = (jax.make_mesh((devices,), ("model",)) if devices > 1 else None)
+    mesh = make_mesh((devices,), ("model",)) if devices > 1 else None
     # equal total KV memory across device counts: 16 x 8-token pages
     ecfg = EngineConfig(num_slots=4, max_seq=48, block_size=8,
                         num_blocks=16, prefill_chunk=8,
@@ -126,6 +129,16 @@ def _multidevice_child(devices: int, spec: bool = False) -> None:
 
 
 def _run_multidevice() -> "tuple[list, dict]":
+    import jax
+
+    if jax.default_backend() != "cpu":
+        # the children fake their devices on the host CPU, and a child
+        # cannot open the chip this process already holds
+        raise SystemExit(
+            "serve_bench multi_device suite: runs only with "
+            "JAX_PLATFORMS=cpu (its children use virtual CPU devices); on "
+            f"{jax.default_backend()} run `python chip_smoke.py --four-chips` "
+            "for the sharded-vs-single-chip comparison")
     rows, outs = [], {}
     for devices, spec in ((1, False), (4, False), (4, True)):
         env = dict(os.environ)

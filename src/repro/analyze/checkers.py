@@ -135,13 +135,13 @@ def check_backend(graph: SiteGraph) -> List[Finding]:
 
 
 # VMEM bytes per kernel grid step (see kernels/daism_matmul.py docstring):
-# the fused shift-plane sweep keeps ~3 live (bm, K_FUSE, bn) slab temporaries
-# (K-independent) + the resident f32 out tile, plus the streamed bf16 a/w
-# tiles — block_k only enters through the streamed tiles now.
+# the fused shift-plane sweep keeps ~3 live (K_FUSE, bm, bn) slab temporaries
+# + the resident f32 out tile, the int32 operand fields staged in scoped
+# VMEM (3 x (bk, bm) + 3 x (bk, bn)), plus the streamed bf16 a/w tiles.
 def _vmem_bytes(bm: int, bk: int, bn: int) -> int:
     from repro.kernels.approx_product import K_FUSE
 
-    return ((3 * bm * min(bk, K_FUSE) * bn + bm * bn) * 4
+    return ((3 * bm * min(bk, K_FUSE) * bn + bm * bn + 3 * bk * (bm + bn)) * 4
             + (bm * bk + bk * bn) * 2)
 
 

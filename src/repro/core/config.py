@@ -90,7 +90,7 @@ class DaismConfig:
     # Pallas tiling knobs (block sizes for the kernel); defaults chosen so the
     # working set fits a 16 MiB VMEM budget with headroom (see kernels/).
     # bm=32 relies on the fused shift-plane sweep: the kernel's peak live
-    # intermediate is (bm, K_FUSE, bn), not (bm, bk, bn).
+    # intermediate is (K_FUSE, bm, bn), not (bm, bk, bn).
     block_m: int = 32
     block_n: int = 128
     block_k: int = 128

@@ -9,22 +9,26 @@ both must stay bit-exact against kernels/ref.py.
 :func:`approx_matmul_tile` is the fused tile contraction: instead of
 materializing the full (bm, bk, bn) product tensor and reducing afterwards,
 it sweeps K in :data:`K_FUSE`-wide sub-chunks, runs the shift-plane product
-on each (bm, K_FUSE, bn) slab, and folds the slab straight into the (bm, bn)
-f32 accumulator. Peak live intermediate drops from O(bm*bk*bn) to
-O(bm*K_FUSE*bn), which is what lets the GEMM kernel raise its M tile and the
-attention kernel keep scores + products VMEM-resident.
+on each (K_FUSE, bm, bn) slab, and folds the slab straight into the (bm, bn)
+f32 accumulator. The sweep is a rolled loop (one sub-chunk of code, however
+large bk), which keeps both the Mosaic program and its VMEM stack small.
+Peak live intermediate drops from O(bm*bk*bn) to O(bm*K_FUSE*bn), which is
+what lets the GEMM kernel raise its M tile and the attention kernel keep
+scores + products VMEM-resident.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.config import Variant
 
 _BIAS = 127
 
 # K-dim sub-chunk width of the fused plane sweep. 8 keeps the live
-# (bm, K_FUSE, bn) slabs at VPU-sublane granularity: with bm = bn = 128 the
+# (K_FUSE, bm, bn) slabs at VPU-sublane granularity: with bm = bn = 128 the
 # ~3 live int32/f32 temporaries total ~1.5 MiB, independent of block_k.
 K_FUSE = 8
 
@@ -127,14 +131,37 @@ def approx_matmul_tile(a_tile, w_tile, variant: Variant, *,
     """
     bm, bk = a_tile.shape
     bn = w_tile.shape[1]
-    sx, ex, mx = decompose_bf16_i32(a_tile)   # (bm, bk)
-    sw, ew, mw = decompose_bf16_i32(w_tile)   # (bk, bn)
-    acc = jnp.zeros((bm, bn), jnp.float32)
-    for lo in range(0, bk, k_fuse):
-        hi = min(lo + k_fuse, bk)
-        slab = compose_products_f32(
-            (sx[:, lo:hi, None], ex[:, lo:hi, None], mx[:, lo:hi, None]),
-            (sw[None, lo:hi, :], ew[None, lo:hi, :], mw[None, lo:hi, :]),
-            variant)
-        acc = acc + slab.sum(axis=1)
-    return acc
+    kf = min(k_fuse, bk)
+    assert bk % kf == 0, (bk, kf)
+    x_fields = [f.T for f in decompose_bf16_i32(a_tile)]   # (bk, bm) each
+    w_fields = decompose_bf16_i32(w_tile)                  # (bk, bn) each
+
+    # Mosaic slices a value only statically and a VMEM ref dynamically only
+    # along sublanes, so the fields are staged K-major in scoped VMEM and the
+    # sweep is a rolled loop over sublane windows. Each slab is laid out
+    # (kf, bm, bn): the window's a-columns are broadcast along lanes, its
+    # w-rows along sublanes, and the slab is summed over the leading axis.
+    def cols(f):     # (bm, kf) -> (kf, bm, 1)
+        return jnp.stack([jax.lax.slice_in_dim(f, j, j + 1, axis=1)
+                          for j in range(kf)])
+
+    def rows(f):     # (kf, bn) -> (kf, 1, bn)
+        return jnp.stack([jax.lax.slice_in_dim(f, j, j + 1, axis=0)
+                          for j in range(kf)])
+
+    def sweep(*refs):
+        for ref, f in zip(refs, (*x_fields, *w_fields)):
+            ref[...] = f
+
+        def body(i, acc):
+            lo = pl.multiple_of(i * kf, kf)
+            slab = compose_products_f32(
+                tuple(cols(r[pl.ds(lo, kf), :].T) for r in refs[:3]),
+                tuple(rows(r[pl.ds(lo, kf), :]) for r in refs[3:]), variant)
+            return acc + slab.sum(axis=0)
+
+        return jax.lax.fori_loop(0, bk // kf, body,
+                                 jnp.zeros((bm, bn), jnp.float32))
+
+    return pl.run_scoped(sweep, *[pltpu.VMEM((bk, bm), jnp.int32)] * 3,
+                         *[pltpu.VMEM((bk, bn), jnp.int32)] * 3)

@@ -14,7 +14,7 @@ fold straight into the (bm, bn) accumulator, so the (bm, bk, bn) product
 tensor of the original kernel never materializes. Working set per step:
 
     a tile (bm, bk) bf16 + w tile (bk, bn) bf16          (streamed from HBM)
-    decomposed int32 fields + (bm, K_FUSE, bn) slabs     (VMEM, K-independent)
+    decomposed int32 fields + (K_FUSE, bm, bn) slabs     (VMEM)
     out tile (bm, bn) f32                                 (resident)
 
 Defaults (bm=32, bk=128, bn=128): the fusion removed the bm*bk*bn term, so

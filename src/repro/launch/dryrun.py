@@ -37,6 +37,10 @@ import jax.numpy as jnp
 RESULTS = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                        "results")
 
+# The chip the roofline terms model: the dry-run compiles on placeholder
+# host devices, so it names its target rather than asking jax.
+TARGET_KIND = "TPU v5 lite"
+
 
 def _mesh(kind: str):
     from repro.launch.mesh import make_production_mesh
@@ -188,7 +192,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, *,
     mem = compiled.memory_analysis()
 
     def costs_of(compiled_):
-        cost = ra.xla_cost(compiled_)
+        cost = compiled_.cost_analysis()
         stats = ra.collective_bytes_from_hlo(compiled_.as_text(), n_chips)
         return (float(cost.get("bytes accessed", 0.0)), stats.wire_bytes,
                 dict(stats.by_op))
@@ -214,9 +218,10 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, *,
     flops_global = flops_thunk()
     flops_dev = flops_global / n_chips
     model_flops = ra.model_flops_estimate(cfg, kind, seq, batch)
-    compute_s = flops_dev / ra.PEAK_FLOPS
-    memory_s = bytes_c / ra.HBM_BW
-    coll_s = wire_c / ra.LINK_BW
+    peaks = ra.chip_peaks(TARGET_KIND)
+    compute_s = flops_dev / peaks.bf16_flops
+    memory_s = bytes_c / peaks.hbm_bw
+    coll_s = wire_c / peaks.link_bw
     terms = {"compute": compute_s, "memory": memory_s, "collective": coll_s}
     bottleneck = max(terms, key=terms.get)
 

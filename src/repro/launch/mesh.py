@@ -1,28 +1,35 @@
-"""Production mesh construction.
+"""Mesh construction: every mesh in the repo is built by :func:`make_mesh`.
 
-``make_production_mesh`` is a FUNCTION (not a module-level constant) so
-importing this module never touches jax device state — required because the
-dry-run must set XLA_FLAGS *before* the first jax device query, while smoke
-tests must keep seeing 1 device.
+The helpers are FUNCTIONS (not module-level constants) so importing this
+module never touches jax device state — required because the dry-run must
+set XLA_FLAGS *before* the first jax device query, while smoke tests must
+keep seeing 1 device.
 """
 from __future__ import annotations
 
-from typing import Optional, Sequence, Tuple
+from typing import Sequence
 
 import jax
-import numpy as np
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str]):
+    """A mesh whose axes are all ``AxisType.Auto``.
+
+    ``jax.make_mesh`` defaults to ``Explicit`` axes, under which
+    ``with_sharding_constraint`` and jit-without-a-mesh-context are refused;
+    the sharding rules, the pipeline and the sharded serving path are all
+    written for compiler-propagated (``Auto``) shardings.
+    """
+    axes = tuple(axes)
+    return jax.make_mesh(tuple(shape), axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single-pod (256 chips) or 2x16x16 multi-pod (512 chips)."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_mesh(shape: Sequence[int], axes: Sequence[str]):
-    """Arbitrary mesh (tests / elastic re-mesh use this)."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    if multi_pod:
+        return make_mesh((2, 16, 16), ("pod", "data", "model"))
+    return make_mesh((16, 16), ("data", "model"))
 
 
 def best_effort_mesh(model_parallel: int = 1):
@@ -33,5 +40,4 @@ def best_effort_mesh(model_parallel: int = 1):
     n = jax.device_count()
     if n % model_parallel != 0:
         raise ValueError(f"{n} devices not divisible by TP={model_parallel}")
-    return jax.make_mesh((n // model_parallel, model_parallel),
-                         ("data", "model"))
+    return make_mesh((n // model_parallel, model_parallel), ("data", "model"))

@@ -106,6 +106,8 @@ def main(argv=None):
     import jax
 
     from repro.configs import get_config
+    from repro.launch.cache import configure_compile_cache
+    from repro.launch.mesh import make_mesh
     from repro.models.registry import build_model
     from repro.serve import (EngineConfig, ServeEngine, parse_tiers,
                              poisson_requests, synthetic_requests)
@@ -141,6 +143,7 @@ def main(argv=None):
         from repro.analyze import preflight
 
         preflight(cfg, engine_cfg=engine_cfg, label=f"serve {args.arch}")
+    configure_compile_cache()
     model = build_model(cfg)
     params, _ = model.init(jax.random.PRNGKey(0))
 
@@ -151,7 +154,7 @@ def main(argv=None):
                 f"--shards {args.shards} does not divide the "
                 f"{jax.device_count()} available devices (on CPU pass "
                 f"--devices {args.shards})")
-        mesh = jax.make_mesh((args.shards,), ("model",))
+        mesh = make_mesh((args.shards,), ("model",))
     engine = ServeEngine(model, params, engine_cfg, mesh=mesh)
     tier_names = [name for name, _ in tiers]
     if args.poisson > 0:

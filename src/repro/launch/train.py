@@ -50,6 +50,7 @@ def main(argv=None):
     from repro.configs import get_config
     from repro.core.config import Backend, DaismConfig, Variant
     from repro.data.synthetic import lm_batches, shard_batch
+    from repro.launch.cache import configure_compile_cache
     from repro.launch.mesh import best_effort_mesh, make_mesh
     from repro.launch.steps import build_artifacts
     from repro.optim import AdamWConfig
@@ -82,6 +83,7 @@ def main(argv=None):
         d, m = (int(x) for x in args.mesh.split("x"))
         mesh = make_mesh((d, m), ("data", "model"))
     print(f"mesh: {dict(zip(mesh.axis_names, mesh.devices.shape))}")
+    configure_compile_cache()
 
     art = build_artifacts(cfg, mesh, opt_cfg=AdamWConfig(lr=args.lr),
                           total_steps=args.steps,

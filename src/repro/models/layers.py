@@ -310,11 +310,9 @@ def self_attention(ctx: Ctx, x: jnp.ndarray, cfg: ArchConfig, *,
             # contiguous: q heads [j*nh/n, ...) read kv heads [j*kh/n, ...))
             from jax.sharding import PartitionSpec as P
 
-            from repro.compat import shard_map
-
             hspec = P(None, None, ax, None)
             pspec = P(None, ax, None)
-            out, ck, cv = shard_map(
+            out, ck, cv = jax.shard_map(
                 body, mesh=sharder.mesh,
                 in_specs=(hspec, hspec, hspec, pspec, pspec, P(), P(), P()),
                 out_specs=(hspec, pspec, pspec),

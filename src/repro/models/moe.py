@@ -2,7 +2,7 @@
 
 Two implementations:
 
-* ``ep`` (production): ``shard_map`` (via repro.compat) over the mesh. Expert weights are
+* ``ep`` (production): ``jax.shard_map`` over the mesh. Expert weights are
   2-D sharded — experts over the ``model`` axis, the contraction dim over the
   data(+pod) axes (FSDP) and all-gathered just-in-time. Each model rank
   dispatches its local tokens to *its own* expert slice with a static
@@ -26,7 +26,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.parallel.sharding import current_sharder
 from repro.policy import policy_expert_matmul
 
@@ -165,7 +164,7 @@ def moe_ffn(ctx: Ctx, x: jnp.ndarray, cfg: ArchConfig
         P("model", None, dp_axes if dp_axes else None),         # w_out
     )
     out_specs = (P(dp_axes if dp_axes else None, None, None), P())
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         ep_body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         check_vma=False)(x, router_w, w_in, wg, w_out)
     return out, aux

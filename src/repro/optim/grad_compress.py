@@ -33,8 +33,8 @@ def quantize_int8(x: jnp.ndarray, scale: jnp.ndarray) -> jnp.ndarray:
 def compressed_psum(grads: Any, axis_names, mode: str = "int8") -> Any:
     """Mean-reduce a gradient pytree across ``axis_names`` with compression.
 
-    Must be called inside a shard_map/pmap context where the axes are bound
-    (use ``repro.compat.shard_map``, which resolves the right jax API).
+    Must be called inside a ``jax.shard_map`` context where the axes are
+    bound.
     """
     if isinstance(axis_names, str):
         axis_names = (axis_names,)

@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
-
 
 def pipeline_apply(layer_fn: Callable, stacked_params, x: jnp.ndarray,
                    mesh: Mesh, *, n_microbatches: int,
@@ -74,5 +72,5 @@ def pipeline_apply(layer_fn: Callable, stacked_params, x: jnp.ndarray,
         return outputs.reshape(x_full.shape)
 
     in_specs = (jax.tree.map(lambda _: P(stage_axis), stacked_params), P())
-    return shard_map(stage_body, mesh=mesh, in_specs=in_specs,
+    return jax.shard_map(stage_body, mesh=mesh, in_specs=in_specs,
                      out_specs=P(), check_vma=False)(stacked_params, x)

@@ -17,18 +17,41 @@ and apply ring-algorithm wire factors with the group size N parsed from
     all-to-all      (N-1)/N x bytes
     collective-permute  1 x bytes
 
-Hardware constants: TPU v5e-class — 197 TFLOP/s bf16, 819 GB/s HBM,
-~50 GB/s/link ICI (assignment-specified).
+The chip's peaks come from :data:`CHIP_PEAKS`, keyed by
+``jax.Device.device_kind``.
 """
 from __future__ import annotations
 
 import dataclasses
 import re
-from typing import Dict, List, Optional, Tuple
+from typing import Dict
 
-PEAK_FLOPS = 197e12          # bf16 / chip
-HBM_BW = 819e9               # bytes/s / chip
-LINK_BW = 50e9               # bytes/s / link
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published peaks of one chip."""
+
+    bf16_flops: float    # FLOP/s, dense bf16 matmul
+    hbm_bw: float        # bytes/s, HBM
+    link_bw: float       # bytes/s, one ICI link
+
+
+# Source: Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16,
+# 16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI per chip over 4 links.
+CHIP_PEAKS: Dict[str, ChipPeaks] = {
+    "TPU v5 lite": ChipPeaks(bf16_flops=197e12, hbm_bw=819e9,
+                             link_bw=1600e9 / 8 / 4),
+}
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """Peaks of ``device_kind``; a kind without a published entry raises."""
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device kind {device_kind!r}; add it to "
+            "CHIP_PEAKS with its source") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -109,76 +132,6 @@ def collective_bytes_from_hlo(hlo_text: str, default_group: int
         n = _group_size(line, default_group)
         stats.add(op, b * _wire_factor(op, n))
     return stats
-
-
-@dataclasses.dataclass
-class Roofline:
-    arch: str
-    shape: str
-    mesh: str
-    flops_per_device: float
-    bytes_per_device: float
-    wire_bytes_per_device: float
-    compute_s: float
-    memory_s: float
-    collective_s: float
-    bottleneck: str
-    model_flops: float
-    useful_ratio: float          # MODEL_FLOPS / (HLO_FLOPs x chips)
-    peak_fraction: float         # compute_s / max(all terms)
-    memory_per_device_gb: float
-    collective_by_op: Dict[str, float]
-
-    def terms(self) -> Dict[str, float]:
-        return {"compute": self.compute_s, "memory": self.memory_s,
-                "collective": self.collective_s}
-
-
-def xla_cost(compiled) -> Dict[str, float]:
-    """``Compiled.cost_analysis()`` normalized across jax versions.
-
-    Newer jax returns a flat dict; the pinned 0.4.x returns a one-element
-    list of dicts (one per computation). Always returns the dict.
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
-
-
-def analyze(compiled, *, arch: str, shape: str, mesh_name: str,
-            n_chips: int, model_flops: float,
-            memory_per_device: Optional[float] = None) -> Roofline:
-    cost = xla_cost(compiled)
-    flops = float(cost.get("flops", 0.0))
-    byts = float(cost.get("bytes accessed", 0.0))
-    stats = collective_bytes_from_hlo(compiled.as_text(), n_chips)
-    compute_s = flops / PEAK_FLOPS
-    memory_s = byts / HBM_BW
-    coll_s = stats.wire_bytes / LINK_BW
-    terms = {"compute": compute_s, "memory": memory_s, "collective": coll_s}
-    bottleneck = max(terms, key=terms.get)
-    total = max(max(terms.values()), 1e-30)
-    if memory_per_device is None:
-        try:
-            ma = compiled.memory_analysis()
-            memory_per_device = (ma.argument_size_in_bytes
-                                 + ma.output_size_in_bytes
-                                 + ma.temp_size_in_bytes)
-        except Exception:
-            memory_per_device = 0.0
-    return Roofline(
-        arch=arch, shape=shape, mesh=mesh_name,
-        flops_per_device=flops, bytes_per_device=byts,
-        wire_bytes_per_device=stats.wire_bytes,
-        compute_s=compute_s, memory_s=memory_s, collective_s=coll_s,
-        bottleneck=bottleneck,
-        model_flops=model_flops,
-        useful_ratio=(model_flops / (flops * n_chips)) if flops else 0.0,
-        peak_fraction=compute_s / total,
-        memory_per_device_gb=memory_per_device / 2**30,
-        collective_by_op=stats.by_op,
-    )
 
 
 def model_flops_estimate(cfg, shape_kind: str, seq: int, batch: int) -> float:

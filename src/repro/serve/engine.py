@@ -13,7 +13,8 @@ Design (PR 1 slot pool -> PR 6 paged pool -> this: sharded + async):
   resolves block tables to gather/scatter indices *inside* the jit'd step:
   decode (S=1) and chunked prefill (S=prefill_chunk) are two fixed shapes
   of the same function, so admission/retirement and table growth never
-  recompile.
+  recompile. Every step donates the page pool (updated in place, on every
+  backend), so ``self.kv`` is the only live reference to it.
 * **Tensor-parallel sharding** — pass ``mesh=`` (with a ``model`` axis) and
   the engine lays params out with the repo's serve Sharder rules, splits
   the page pool's kv-heads dim over the same axis
@@ -344,7 +345,7 @@ class _PolicyGroup:
     metadata (block tables, write offsets, last tokens)."""
 
     def __init__(self, label: str, policy: Optional[ApproxPolicy], model,
-                 cfg: EngineConfig, donate: bool, sharder=None):
+                 cfg: EngineConfig, sharder=None):
         self.label = label
         self.policy = policy
         self.model = model
@@ -378,7 +379,7 @@ class _PolicyGroup:
                                            axis=1)  # (R, 1, V) at true length
                 return jnp.argmax(last[:, 0, :], -1), new_kv
 
-        self.step_fn = jax.jit(step, donate_argnums=(1,) if donate else ())
+        self.step_fn = jax.jit(step, donate_argnums=(1,))
 
         self.verify_fn = None
         if cfg.spec_k:
@@ -391,8 +392,7 @@ class _PolicyGroup:
                     return model.paged_verify_step(params, tokens, cache,
                                                    block_size=block_size)
 
-            self.verify_fn = jax.jit(verify,
-                                     donate_argnums=(1,) if donate else ())
+            self.verify_fn = jax.jit(verify, donate_argnums=(1,))
 
     @property
     def prefill_rows(self) -> Dict[int, RequestState]:
@@ -452,9 +452,6 @@ class ServeEngine:
                 "(daism-lint SRV007)")
         self.pool = BlockPool(cfg.blocks, cfg.block_size)
         self.kv = model.init_paged_cache(cfg.blocks, cfg.block_size)
-        # donation: in-place pool updates (not implemented on CPU — jax
-        # would warn and copy anyway)
-        self._donate = jax.default_backend() != "cpu"
         if mesh is not None:
             from repro.models.module import axes_tree
             from repro.parallel.sharding import (Sharder, base_rules,
@@ -505,8 +502,7 @@ class ServeEngine:
                         params, tokens, cache, block_size=cfg.block_size)
                 return jnp.argmax(logits[:, 0, :], -1), new_kv
 
-            self._draft_step = jax.jit(
-                draft, donate_argnums=(1,) if self._donate else ())
+            self._draft_step = jax.jit(draft, donate_argnums=(1,))
 
         self.groups: Dict[Optional[ApproxPolicy], _PolicyGroup] = {}
         self._pending_alloc: Dict[int, Tuple[List[int], int]] = {}
@@ -616,8 +612,7 @@ class ServeEngine:
                 from repro.models.registry import build_model
 
                 model = build_model(self.model.cfg.with_policy(policy))
-            group = _PolicyGroup(label, key, model, self.cfg, self._donate,
-                                 self.sharder)
+            group = _PolicyGroup(label, key, model, self.cfg, self.sharder)
             group.spec_on = self._spec_eligible(key)
             self.groups[key] = group
         return group

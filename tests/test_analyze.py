@@ -89,9 +89,9 @@ def test_deprecated_daism_shim_warns():
 def test_tiling_padding_and_vmem_warnings():
     from repro.policy import EXACT, ApproxPolicy, Rule
     # spec grammar has no block syntax: build the policy programmatically.
-    # The fused plane sweep made the VMEM estimate K-independent (live slabs
-    # are (bm, K_FUSE, bn)), so only very large M/N tiles can blow the
-    # budget now — block_k only enters through the streamed bf16 tiles.
+    # The fused plane sweep keeps the live slabs at (K_FUSE, bm, bn), so
+    # only very large tiles blow the budget — block_k enters through the
+    # staged int32 fields and the streamed bf16 tiles.
     bad = DaismConfig(variant=Variant.PC3_TR, backend=Backend.PALLAS,
                       block_m=2048, block_n=1000, block_k=2048)
     pol = ApproxPolicy(rules=(Rule("*/ffn/*", bad),), default=EXACT)

@@ -3,7 +3,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import shard_map
+from repro.launch.mesh import make_mesh
 from repro.optim import (AdamWConfig, apply_updates, cosine_with_warmup,
                          init_state, quantize_int8)
 from repro.optim.grad_compress import compressed_psum
@@ -69,11 +69,11 @@ def test_quantize_int8_roundtrip_error():
 def test_compressed_psum_modes_single_device():
     """With a single device axis the mean must equal the input (up to
     quantization error)."""
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     g = {"w": jnp.asarray(np.random.default_rng(1).normal(size=(64,)),
                           jnp.float32)}
     for mode in ("none", "bf16", "int8"):
-        out = shard_map(
+        out = jax.shard_map(
             lambda t: compressed_psum(t, ("data",), mode=mode),
             mesh=mesh, in_specs=(jax.sharding.PartitionSpec(),),
             out_specs=jax.sharding.PartitionSpec(), check_vma=False)(g)

@@ -14,9 +14,10 @@ _SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import jax, jax.numpy as jnp, numpy as np
     from jax import lax
+    from repro.launch.mesh import make_mesh
     from repro.parallel.pipeline import pipeline_apply
 
-    mesh = jax.make_mesh((4,), ("stage",))
+    mesh = make_mesh((4,), ("stage",))
     L, B, D = 8, 16, 32
     rng = np.random.default_rng(0)
     params = {"w": jnp.asarray(rng.normal(size=(L, D, D)) / np.sqrt(D),

@@ -4,6 +4,7 @@ validated against fully-unrolled ground truth."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from jax import lax
 
 from repro.roofline import analysis as ra
@@ -21,8 +22,8 @@ def test_xla_counts_scan_body_once():
     """The motivating defect: cost_analysis under-reports scanned layers."""
     w = jnp.zeros((8, 128, 128), jnp.bfloat16)
     x = jnp.zeros((128, 128), jnp.bfloat16)
-    cs = ra.xla_cost(jax.jit(_scan_mm()).lower(x, w).compile())
-    cu = ra.xla_cost(jax.jit(_scan_mm(unroll=8)).lower(x, w).compile())
+    cs = jax.jit(_scan_mm()).lower(x, w).compile().cost_analysis()
+    cu = jax.jit(_scan_mm(unroll=8)).lower(x, w).compile().cost_analysis()
     assert float(cs["flops"]) < 0.2 * float(cu["flops"])
 
 
@@ -99,7 +100,7 @@ def test_probe_correction_matches_full_unroll():
     w = jnp.zeros((L, 256, 256), jnp.bfloat16)
 
     def bytes_of(unroll):
-        c = ra.xla_cost(jax.jit(model(unroll)).lower(x, w).compile())
+        c = jax.jit(model(unroll)).lower(x, w).compile().cost_analysis()
         return float(c["bytes accessed"])
 
     b1, b2, bfull = bytes_of(1), bytes_of(2), bytes_of(L)
@@ -119,3 +120,10 @@ def test_model_flops_estimate_moe_active_params():
     # active ~22B of 235B
     want_moe = 6 * 22.5e9 * 4096 * 256
     assert abs(moe - want_moe) / want_moe < 0.15
+
+
+def test_chip_peaks_table_is_keyed_by_device_kind():
+    v5e = ra.chip_peaks("TPU v5 lite")
+    assert (v5e.bf16_flops, v5e.hbm_bw) == (197e12, 819e9)
+    with pytest.raises(KeyError, match="no published peaks"):
+        ra.chip_peaks("cpu")

@@ -29,6 +29,7 @@ _COMMON = textwrap.dedent("""
     import jax
     import numpy as np
     from repro.configs import get_config
+    from repro.launch.mesh import make_mesh
     from repro.models.registry import build_model
     from repro.serve import (EngineConfig, Request, ServeEngine,
                              poisson_requests)
@@ -56,7 +57,7 @@ _SHARDED = _COMMON + textwrap.dedent("""
         tiers=TIERS))
     ref = outputs(base.run(requests(0)))
 
-    mesh = jax.make_mesh((4,), ("model",))
+    mesh = make_mesh((4,), ("model",))
     eng = ServeEngine(model, params, EngineConfig(
         num_slots=4, max_seq=48, block_size=8, prefill_chunk=8,
         tiers=TIERS, shards=4), mesh=mesh)
@@ -80,7 +81,7 @@ _PREEMPT = _COMMON + textwrap.dedent("""
         tiers=TIERS))
     ref = outputs(base.run(fresh()))
 
-    mesh = jax.make_mesh((4,), ("model",))
+    mesh = make_mesh((4,), ("model",))
     # 8-page pool against rows growing to 3 pages each: exhaustion is
     # guaranteed under concurrent decode, so the swap path really runs
     eng = ServeEngine(model, params, EngineConfig(
@@ -106,7 +107,7 @@ _SPEC = _COMMON + textwrap.dedent("""
         tiers=TIERS))
     ref = outputs(base.run(fresh()))
 
-    mesh = jax.make_mesh((4,), ("model",))
+    mesh = make_mesh((4,), ("model",))
     # sharded + preempting + speculative: the draft chain, batched verify,
     # page rollback, and swap path all cross the 4-way mesh together
     eng = ServeEngine(model, params, EngineConfig(
@@ -126,7 +127,7 @@ _SPEC = _COMMON + textwrap.dedent("""
 """)
 
 _MISMATCH = _COMMON + textwrap.dedent("""
-    mesh = jax.make_mesh((4,), ("model",))
+    mesh = make_mesh((4,), ("model",))
     try:
         ServeEngine(model, params, EngineConfig(
             num_slots=3, max_seq=48, block_size=8, prefill_chunk=8,

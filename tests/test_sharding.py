@@ -6,12 +6,13 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
+from repro.launch.mesh import make_mesh
 from repro.parallel.sharding import Sharder, base_rules
 
 
 @pytest.fixture()
 def sharder():
-    mesh = jax.make_mesh((1,), ("data",))  # single-device 'data' mesh
+    mesh = make_mesh((1,), ("data",))  # single-device 'data' mesh
     rules = base_rules(False)
     return Sharder(mesh, rules)
 
@@ -23,7 +24,7 @@ def test_spec_basic(sharder):
 
 
 def test_divisibility_fallback():
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     s = Sharder(mesh, {"kv_heads": "model"})
     # only 1 device: axis size 1 divides everything
     assert s.spec(("kv_heads",), (4,)) == P("model")
@@ -33,7 +34,7 @@ def test_divisibility_drops_nondividing_axis():
     import os
     # simulate a 16-wide axis via rule table arithmetic (no devices needed
     # for the pure spec logic: fake axis sizes)
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     s = Sharder(mesh, {"kv_heads": "model"})
     s._axis_sizes = {"model": 16}
     assert s.spec(("kv_heads",), (4,)) == P()      # 4 % 16 != 0 -> replicate
@@ -41,7 +42,7 @@ def test_divisibility_drops_nondividing_axis():
 
 
 def test_axis_used_once_per_spec():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     s = Sharder(mesh, {"a": "data", "b": "data"})
     s._axis_sizes = {"data": 4}
     spec = s.spec(("a", "b"), (8, 8))
@@ -50,7 +51,7 @@ def test_axis_used_once_per_spec():
 
 
 def test_seq_cache_rule_switch():
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     base = Sharder(mesh, base_rules(False))
     seqc = Sharder(mesh, base_rules(False, seq_sharded_cache=True))
     base._axis_sizes = {"model": 16}
