@@ -94,4 +94,5 @@ def daism_matmul_kernel(
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         interpret=auto_interpret(interpret),
+        name="daism_matmul",
     )(a, w)

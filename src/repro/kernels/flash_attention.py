@@ -162,6 +162,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             pltpu.VMEM((block_q, d), jnp.float32),         # acc
         ],
         interpret=auto_interpret(interpret),
+        name="flash_attention",
     )(q, k, v)
 
 

@@ -178,24 +178,7 @@ def main(argv=None):
           f"{args.slots} rows/group, {engine.cfg.blocks} x "
           f"{args.block_size}-token KV pages, arrivals {arrivals} ==")
     for ev in report.events:
-        if ev["event"] == "admit":
-            joined = " (joined running batch)" if ev["joined_running"] else ""
-            cached = (f", {ev['cached_blocks']} cached"
-                      if ev.get("cached_blocks") else "")
-            print(f"step {ev['step']:4d}  admit  req {ev['request_id']} "
-                  f"-> {ev['group']}/row {ev['slot']} "
-                  f"[{ev['blocks']} pages{cached}]{joined}")
-        elif ev["event"] == "preempt":
-            print(f"step {ev['step']:4d}  preempt req {ev['request_id']} "
-                  f"({ev['group']}/row {ev['slot']}: {ev['blocks']} pages "
-                  "swapped to host)")
-        elif ev["event"] == "resume":
-            print(f"step {ev['step']:4d}  resume req {ev['request_id']} "
-                  f"-> {ev['group']}/row {ev['slot']} "
-                  f"[{ev['blocks']} pages restored]")
-        else:
-            print(f"step {ev['step']:4d}  retire req {ev['request_id']} "
-                  f"({ev['group']}/row {ev['slot']} freed, {ev['reason']})")
+        print(event_line(ev))
     print(report.summary())
     if args.tiers or args.policy or args.variant != "exact":
         print(engine.resolution_report())
@@ -257,6 +240,31 @@ def main(argv=None):
         print(f"SMOKE-OK: speculative decoding took {report.spec_steps} "
               f"verify step(s), accept rate {report.spec_accept_rate:.2f}, "
               f"{report.spec_tokens_per_step:.2f} tokens/step")
+
+
+def event_line(ev) -> str:
+    """One line of the timeline for an engine event (``ServeEngine.events``)."""
+    head = f"step {ev['step']:4d}  "
+    if ev["event"] == "admit":
+        joined = " (joined running batch)" if ev["joined_running"] else ""
+        cached = (f", {ev['cached_blocks']} cached"
+                  if ev.get("cached_blocks") else "")
+        return (f"{head}admit  req {ev['request_id']} "
+                f"-> {ev['group']}/row {ev['slot']} "
+                f"[{ev['blocks']} pages{cached}]{joined}")
+    if ev["event"] == "preempt":
+        return (f"{head}preempt req {ev['request_id']} "
+                f"({ev['group']}/row {ev['slot']}: {ev['blocks']} pages "
+                "swapped to host)")
+    if ev["event"] == "resume":
+        return (f"{head}resume req {ev['request_id']} "
+                f"-> {ev['group']}/row {ev['slot']} "
+                f"[{ev['blocks']} pages restored]")
+    if ev["event"] == "spec_off":
+        return (f"{head}spec off for group {ev['group']} (acceptance EWMA "
+                f"{ev['ewma']})")
+    return (f"{head}retire req {ev['request_id']} "
+            f"({ev['group']}/row {ev['slot']} freed, {ev['reason']})")
 
 
 if __name__ == "__main__":

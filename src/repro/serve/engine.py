@@ -59,6 +59,13 @@ Design (PR 1 slot pool -> PR 6 paged pool -> this: sharded + async):
 * **Accounting** — per-request TTFT / latency, inter-token gap
   percentiles, engine tok/s + step percentiles, KV utilization, peak
   concurrency, prefix-cache hits, preemptions/resumes, host idle time.
+* **Profiler spans** — each tick is a ``jax.profiler`` step span
+  (``engine.tick``) holding one span per host phase (``engine.grow``,
+  ``engine.launch``, ``engine.admit``, ``engine.fetch``, ``engine.apply``,
+  ``engine.swap_out``/``engine.swap_in``) with its counts as stats. They
+  land on the host plane of the same trace as the device ops, on the
+  profiler's clock, so each device idle gap can be put down to a host
+  phase. With the profiler off a span costs ~1-2 us of host time.
 
 Greedy (argmax) sampling: deterministic, so paged batched decode is
 token-identical to the single-request ``decode_step`` path — asserted in
@@ -75,6 +82,7 @@ from typing import Any, Dict, List, Optional, Sequence, Set, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.policy import ApproxPolicy, parse_policy
 from repro.runtime.watchdog import StepWatchdog
@@ -379,7 +387,16 @@ class _PolicyGroup:
                                            axis=1)  # (R, 1, V) at true length
                 return jnp.argmax(last[:, 0, :], -1), new_kv
 
-        self.step_fn = jax.jit(step, donate_argnums=(1,))
+        # one trace, two programs named by step kind: a device trace
+        # shows jit_step_prefill (S=prefill_chunk) and jit_step_decode (S=1)
+        def step_prefill(*args):
+            return step(*args)
+
+        def step_decode(*args):
+            return step(*args)
+
+        self.prefill_fn = jax.jit(step_prefill, donate_argnums=(1,))
+        self.decode_fn = jax.jit(step_decode, donate_argnums=(1,))
 
         self.verify_fn = None
         if cfg.spec_k:
@@ -537,7 +554,6 @@ class ServeEngine:
         self._step_times: List[float] = []
         self._prefill_s = 0.0
         self._idle_s = 0.0
-        self._tok_gaps: List[float] = []
         self._util_samples: List[float] = []
         self._util_peak = 0.0
         self._peak_active = 0
@@ -675,6 +691,7 @@ class ServeEngine:
         return True
 
     def _admit(self, group: _PolicyGroup, admitted: List[RequestState]):
+        now = time.perf_counter()
         for state in admitted:
             table, cached_len = self._pending_alloc.pop(state.request_id)
             group.tables[state.slot] = SENTINEL
@@ -682,6 +699,7 @@ class ServeEngine:
             if state.swap is not None:
                 self._swap_restore(group, state, table)
                 continue
+            state.admit_time = now  # first admission: resumes skip this
             state.next_pos = cached_len
             state.cached_len = cached_len
             self._event("admit", state, state.slot,
@@ -722,9 +740,10 @@ class ServeEngine:
         n_blocks = blocks_needed(state.seq_len, self.cfg.block_size)
         table = np.full((self.cfg.max_blocks_per_seq,), SENTINEL, np.int32)
         table[:n_blocks] = group.tables[slot, :n_blocks]
-        k, v = self._swap_out(self.kv, jnp.asarray(table))
-        state.swap = {"k": np.asarray(k), "v": np.asarray(v),
-                      "blocks": n_blocks}
+        with TraceAnnotation("engine.swap_out", blocks=n_blocks):
+            k, v = self._swap_out(self.kv, jnp.asarray(table))
+            state.swap = {"k": np.asarray(k), "v": np.asarray(v),
+                          "blocks": n_blocks}
         self._swapped_blocks += n_blocks
         self.pool.free(state.request_id)
         group.sched.requeue(slot)
@@ -744,9 +763,10 @@ class ServeEngine:
         # blocks cover future positions and are written by decode itself
         t = np.full((self.cfg.max_blocks_per_seq,), SENTINEL, np.int32)
         t[:n_old] = table[:n_old]
-        self.kv = self._swap_in(self.kv, jnp.asarray(t),
-                                jnp.asarray(swap["k"]),
-                                jnp.asarray(swap["v"]))
+        with TraceAnnotation("engine.swap_in", blocks=n_old):
+            self.kv = self._swap_in(self.kv, jnp.asarray(t),
+                                    jnp.asarray(swap["k"]),
+                                    jnp.asarray(swap["v"]))
         self.pool.advance(state.request_id, state.seq_len)
         self.pool.commit_prefix(state.request_id)
         group.last_tok[state.slot] = state.output[-1]
@@ -790,9 +810,7 @@ class ServeEngine:
                       token: int):
         now = time.perf_counter()
         if state.last_token_time:
-            gap = now - state.last_token_time
-            state.token_gaps_s.append(gap)
-            self._tok_gaps.append(gap)
+            state.token_gaps_s.append(now - state.last_token_time)
         state.last_token_time = now
         state.output.append(token)
         group.last_tok[state.slot] = token
@@ -821,25 +839,30 @@ class ServeEngine:
         cfg = self.cfg
         chunk = cfg.prefill_chunk
         r = cfg.num_slots
-        tokens = np.zeros((r, chunk), np.int32)
-        tables = np.full_like(group.tables, SENTINEL)
-        pos = np.zeros((r,), np.int32)
-        last_idx = np.zeros((r,), np.int32)
-        finishing: Set[int] = set()
-        for slot, state in rows.items():
-            prompt = state.request.prompt
-            piece = prompt[state.next_pos:state.next_pos + chunk]
-            tokens[slot, :len(piece)] = piece
-            tables[slot] = group.tables[slot]
-            pos[slot] = state.next_pos
-            last_idx[slot] = len(piece) - 1
-            if state.next_pos + len(piece) == len(prompt):
-                finishing.add(slot)
-            state.next_pos += len(piece)
-        t0 = time.perf_counter()
-        tok, self.kv = group.step_fn(
-            self.params, self.kv, jnp.asarray(tokens), jnp.asarray(tables),
-            jnp.asarray(pos), jnp.asarray(last_idx))
+        with TraceAnnotation("engine.launch", kind="prefill",
+                             group=group.label) as span:
+            tokens = np.zeros((r, chunk), np.int32)
+            tables = np.full_like(group.tables, SENTINEL)
+            pos = np.zeros((r,), np.int32)
+            last_idx = np.zeros((r,), np.int32)
+            finishing: Set[int] = set()
+            live = 0
+            for slot, state in rows.items():
+                prompt = state.request.prompt
+                piece = prompt[state.next_pos:state.next_pos + chunk]
+                tokens[slot, :len(piece)] = piece
+                tables[slot] = group.tables[slot]
+                pos[slot] = state.next_pos
+                last_idx[slot] = len(piece) - 1
+                if state.next_pos + len(piece) == len(prompt):
+                    finishing.add(slot)
+                state.next_pos += len(piece)
+                live += len(piece)
+            span.set_metadata(rows=len(rows), tokens=live, padded=r * chunk)
+            t0 = time.perf_counter()
+            tok, self.kv = group.prefill_fn(
+                self.params, self.kv, jnp.asarray(tokens),
+                jnp.asarray(tables), jnp.asarray(pos), jnp.asarray(last_idx))
         return {"group": group, "kind": "prefill", "rows": rows,
                 "finishing": finishing, "tok": tok, "t0": t0}
 
@@ -852,16 +875,19 @@ class ServeEngine:
         if not rows:
             return None
         r = self.cfg.num_slots
-        tables = np.full_like(group.tables, SENTINEL)
-        pos = np.zeros((r,), np.int32)
-        for slot, state in rows.items():
-            tables[slot] = group.tables[slot]
-            pos[slot] = state.seq_len  # write position of the fed-back token
-        t0 = time.perf_counter()
-        tok, self.kv = group.step_fn(
-            self.params, self.kv, jnp.asarray(group.last_tok[:, None]),
-            jnp.asarray(tables), jnp.asarray(pos),
-            jnp.zeros((r,), jnp.int32))
+        with TraceAnnotation("engine.launch", kind="decode",
+                             group=group.label, rows=len(rows),
+                             tokens=len(rows), padded=r):
+            tables = np.full_like(group.tables, SENTINEL)
+            pos = np.zeros((r,), np.int32)
+            for slot, state in rows.items():
+                tables[slot] = group.tables[slot]
+                pos[slot] = state.seq_len  # fed-back token's write position
+            t0 = time.perf_counter()
+            tok, self.kv = group.decode_fn(
+                self.params, self.kv, jnp.asarray(group.last_tok[:, None]),
+                jnp.asarray(tables), jnp.asarray(pos),
+                jnp.zeros((r,), jnp.int32))
         return {"group": group, "kind": "decode", "rows": rows,
                 "tok": tok, "t0": t0}
 
@@ -887,25 +913,31 @@ class ServeEngine:
             return None
         cfg = self.cfg
         r = cfg.num_slots
-        tables = np.full_like(group.tables, SENTINEL)
-        pos = np.zeros((r,), np.int32)
-        caps: Dict[int, int] = {}
-        for slot, state in rows.items():
-            tables[slot] = group.tables[slot]
-            pos[slot] = state.seq_len  # write offset of the candidate window
-            cov = int((group.tables[slot] != SENTINEL).sum()) * cfg.block_size
-            caps[slot] = max(0, cov - 1 - state.seq_len)
-        t0 = time.perf_counter()
-        jt = jnp.asarray(tables)
-        kv = self.kv
-        toks = [jnp.asarray(group.last_tok)]
-        for j in range(cfg.spec_k):
-            nxt, kv = self._draft_step(self.params, kv, toks[-1][:, None],
-                                       jt, jnp.asarray(pos + j))
-            toks.append(nxt)
-        cand = jnp.stack(toks, axis=1)  # (R, spec_k+1) candidate window
-        greedy, n_acc, self.kv = group.verify_fn(self.params, kv, cand, jt,
-                                                 jnp.asarray(pos))
+        s = cfg.spec_k + 1
+        with TraceAnnotation("engine.launch", kind="spec", group=group.label,
+                             rows=len(rows), tokens=len(rows) * s,
+                             padded=r * s):
+            tables = np.full_like(group.tables, SENTINEL)
+            pos = np.zeros((r,), np.int32)
+            caps: Dict[int, int] = {}
+            for slot, state in rows.items():
+                tables[slot] = group.tables[slot]
+                pos[slot] = state.seq_len  # write offset of the candidates
+                cov = (int((group.tables[slot] != SENTINEL).sum())
+                       * cfg.block_size)
+                caps[slot] = max(0, cov - 1 - state.seq_len)
+            t0 = time.perf_counter()
+            jt = jnp.asarray(tables)
+            kv = self.kv
+            toks = [jnp.asarray(group.last_tok)]
+            for j in range(cfg.spec_k):
+                nxt, kv = self._draft_step(self.params, kv,
+                                           toks[-1][:, None], jt,
+                                           jnp.asarray(pos + j))
+                toks.append(nxt)
+            cand = jnp.stack(toks, axis=1)  # (R, spec_k+1) candidate window
+            greedy, n_acc, self.kv = group.verify_fn(self.params, kv, cand,
+                                                     jt, jnp.asarray(pos))
         return {"group": group, "kind": "spec", "rows": rows, "tok": greedy,
                 "n_acc": n_acc, "caps": caps, "t0": t0}
 
@@ -914,15 +946,26 @@ class ServeEngine:
         the loop; the blocked time is the tick's idle accounting."""
         if "np_tok" in rec:
             return
-        t0 = time.perf_counter()
-        rec["np_tok"] = np.asarray(rec["tok"])
-        if "n_acc" in rec:
-            rec["np_acc"] = np.asarray(rec["n_acc"])
-        t1 = time.perf_counter()
+        with TraceAnnotation("engine.fetch", kind=rec["kind"]):
+            t0 = time.perf_counter()
+            rec["np_tok"] = np.asarray(rec["tok"])
+            if "n_acc" in rec:
+                rec["np_acc"] = np.asarray(rec["n_acc"])
+            t1 = time.perf_counter()
         self._idle_s += t1 - t0
         rec["dt"] = t1 - rec["t0"]
 
     def _apply(self, rec: dict):
+        """``_fold`` in an ``engine.apply`` span that counts the tokens
+        appended."""
+        states = rec["rows"].values()
+        with TraceAnnotation("engine.apply", kind=rec["kind"]) as span:
+            before = sum(len(st.output) for st in states)
+            self._fold(rec)
+            span.set_metadata(
+                emitted=sum(len(st.output) for st in states) - before)
+
+    def _fold(self, rec: dict):
         """Fold a fetched step's tokens back into scheduler/pool state."""
         group, rows, tok = rec["group"], rec["rows"], rec["np_tok"]
         dt = rec["dt"]
@@ -930,7 +973,6 @@ class ServeEngine:
             self._prefill_s += dt
             now = time.perf_counter()
             for slot, state in rows.items():
-                state.prefill_s += dt
                 if slot in rec["finishing"]:
                     state.first_token_time = now
                     self.pool.commit_prefix(state.request_id)
@@ -1046,22 +1088,33 @@ class ServeEngine:
         4. post-retirement admission (pages just freed; preemption/resume
            allowed here — nothing is in flight).
 
+        The tick is one ``engine.tick`` profiler step span; phases 0, 2 and
+        4 are ``engine.grow`` and ``engine.admit`` spans, and each launch,
+        fetch and apply is a span of its own.
+
         Returns False when fully drained."""
         if not any(g.sched.has_work for g in self.groups.values()):
             return False
+        with StepTraceAnnotation("engine.tick", step_num=self.step):
+            self._tick()
+        return any(g.sched.has_work for g in self.groups.values())
+
+    def _tick(self):
         stalled: Set[int] = set()
-        for group in self.groups.values():
-            # speculative rows want spec_k extra positions of coverage, but
-            # only in preempt mode (on-demand growth + truncate rollback);
-            # a whole-lifetime reservation already covers every position
-            # acceptance can reach, so reserve mode never over-allocates
-            ahead = (self.cfg.spec_k
-                     if group.spec_on and self.cfg.preempt else 0)
-            for _slot, state in list(group.decode_rows.items()):
-                if state.request_id not in self.pool:
-                    continue  # preempted as a victim earlier this phase
-                if not self._ensure_blocks(group, state, ahead=ahead):
-                    stalled.add(state.request_id)
+        with TraceAnnotation("engine.grow"):
+            for group in self.groups.values():
+                # speculative rows want spec_k extra positions of coverage,
+                # but only in preempt mode (on-demand growth + truncate
+                # rollback); a whole-lifetime reservation already covers
+                # every position acceptance can reach, so reserve mode
+                # never over-allocates
+                ahead = (self.cfg.spec_k
+                         if group.spec_on and self.cfg.preempt else 0)
+                for _slot, state in list(group.decode_rows.items()):
+                    if state.request_id not in self.pool:
+                        continue  # preempted as a victim earlier this phase
+                    if not self._ensure_blocks(group, state, ahead=ahead):
+                        stalled.add(state.request_id)
         inflight = []
         for group in self.groups.values():
             rec = self._launch_prefill(group)
@@ -1076,17 +1129,18 @@ class ServeEngine:
                 inflight.append(rec)
                 if not self.cfg.overlap:
                     self._fetch(rec)
-        now = time.perf_counter()
-        self._stamp_arrivals(now)
-        admitted = self._admit_all(allow_preempt=False)
-        self._sample_util()
+        with TraceAnnotation("engine.admit", phase="overlap"):
+            self._stamp_arrivals(time.perf_counter())
+            admitted = self._admit_all(allow_preempt=False)
+            self._sample_util()
         for rec in inflight:
             # fetch+apply interleaved: applying an earlier record's host
             # bookkeeping (token append, prefix commit, retirement) runs
             # while later records are still computing on the device
             self._fetch(rec)
             self._apply(rec)
-        admitted |= self._admit_all(allow_preempt=self.cfg.preempt)
+        with TraceAnnotation("engine.admit", phase="post"):
+            admitted |= self._admit_all(allow_preempt=self.cfg.preempt)
         self._peak_active = max(
             self._peak_active,
             sum(len(g.sched.active) for g in self.groups.values()))
@@ -1104,7 +1158,6 @@ class ServeEngine:
                     "buffer together cannot host any runnable request "
                     "(undersized swap_blocks? see daism-lint SRV008)")
         self.step += 1
-        return any(g.sched.has_work for g in self.groups.values())
 
     # -- driver ------------------------------------------------------------
 
@@ -1129,7 +1182,7 @@ class ServeEngine:
         decode_s = float(sum(self._step_times))
         # prefill produces 1 token/request; the rest ride decode steps
         decode_tokens = generated - len(done)
-        gaps_ms = [g * 1e3 for g in self._tok_gaps]
+        gaps_ms = [g * 1e3 for s in done for g in s.token_gaps_s]
         return ServeReport(
             completed=done,
             wall_s=wall,

@@ -68,15 +68,17 @@ class RequestState:
     # next_pos == len(prompt) and the first token has been emitted.
     next_pos: int = 0
     cached_len: int = 0
-    # wall-clock accounting (seconds, engine-stamped). arrival_time is when
-    # the request became admissible — equal to submit_time for immediate
+    # wall-clock accounting (seconds on time.perf_counter, engine-stamped):
+    # submit -> admit -> first token -> finish. arrival_time is when the
+    # request became admissible — equal to submit_time for immediate
     # arrivals, stamped later for arrival_step-gated trace replays, so
     # TTFT/latency never include simulated pre-arrival queueing.
+    # admit_time is the first admission; a swapped request's resume keeps it.
     submit_time: float = 0.0
     arrival_time: float = 0.0
+    admit_time: float = 0.0
     first_token_time: float = 0.0
     finish_time: float = 0.0
-    prefill_s: float = 0.0  # wall time of the prefill chunks it rode in
     last_token_time: float = 0.0   # stamp of the latest emitted token
     token_gaps_s: List[float] = dataclasses.field(default_factory=list)
     # preemption/swap bookkeeping (engine-owned): ``swap`` holds the

@@ -636,3 +636,22 @@ def test_spec_controller_disables_low_acceptance_group(served):
     # permanent for the run: further observations don't resurrect it
     engine._update_spec_controller(group, [1.0])
     assert group.spec_on is False
+
+
+@pytest.mark.parametrize("ev,text", [
+    (dict(event="admit", joined_running=True, blocks=2, cached_blocks=1),
+     "admit  req 3 -> base/row 1 [2 pages, 1 cached] (joined running"),
+    (dict(event="preempt", blocks=2), "preempt req 3 (base/row 1: 2 pages"),
+    (dict(event="resume", blocks=3), "resume req 3 -> base/row 1 [3 pages"),
+    (dict(event="retire", reason="eos"), "retire req 3 (base/row 1 freed, eos)"),
+    (dict(event="spec_off", request_id=-1, slot=-1, ewma=0.125),
+     "spec off for group base (acceptance EWMA 0.125)"),
+], ids=lambda v: v["event"] if isinstance(v, dict) else "")
+def test_serve_cli_prints_every_event_kind(ev, text):
+    """The CLI's timeline has a line for each event the engine emits; a
+    ``spec_off`` event has no request, row or reason."""
+    from repro.launch.serve import event_line
+
+    line = event_line(dict(dict(step=7, request_id=3, slot=1, group="base"),
+                           **ev))
+    assert line.startswith("step    7  ") and text in line

@@ -72,6 +72,7 @@ def test_daism_matmul_kernel_compiles(one_chip, variant, m, k, n):
                                          interpret=False),
         one_chip, (m, k), (k, n))
     assert "tpu_custom_call" in text
+    assert "%daism_matmul" in text    # the kernel's name, as traces show it
 
 
 @pytest.mark.parametrize("variant", [None, Variant.PC3_TR],
@@ -83,6 +84,7 @@ def test_flash_attention_compiles(one_chip, variant):
                                         interpret=False),
         one_chip, shape, shape, shape)
     assert "tpu_custom_call" in text
+    assert "%flash_attention" in text
 
 
 def test_daism_matmul_pallas_compiles_padded_decode(one_chip):
