@@ -42,6 +42,7 @@ from typing import List
 import jax
 
 from repro.core.config import Backend
+from repro.kernels.ops import col_tile, row_tile
 from repro.policy import (OpKind, auto_interpret, describe_config,
                           parse_policy, validate_for_dtype)
 
@@ -155,7 +156,10 @@ def check_tiling(graph: SiteGraph, *,
             continue
         m, k, n = s.dims
         c = s.config
-        pad = {"m": (m, c.block_m), "k": (k, c.block_k), "n": (n, c.block_n)}
+        # the tiles the kernel really uses
+        bm = row_tile(m, c.block_m)
+        bn = col_tile(n, bm, c.block_m, c.block_n)
+        pad = {"m": (m, bm), "k": (k, c.block_k), "n": (n, bn)}
         ragged = {ax: (dim, blk) for ax, (dim, blk) in pad.items()
                   if dim % blk}
         if ragged:
@@ -164,17 +168,17 @@ def check_tiling(graph: SiteGraph, *,
             findings.append(Finding(
                 "TIL001", "warning", "tiling",
                 f"GEMM dims (m={m}, k={k}, n={n}) not divisible by Pallas "
-                f"blocks (bm={c.block_m}, bk={c.block_k}, bn={c.block_n}); "
+                f"blocks (bm={bm}, bk={c.block_k}, bn={bn}); "
                 f"the kernel pads {', '.join(padded)} — wasted compute and "
                 "an extra compiled shape",
                 site=s.path))
-        vmem = _vmem_bytes(c.block_m, c.block_k, c.block_n)
+        vmem = _vmem_bytes(bm, c.block_k, bn)
         if vmem > vmem_budget_mib * (1 << 20):
             findings.append(Finding(
                 "TIL002", "warning", "tiling",
                 f"estimated per-kernel VMEM footprint {vmem / (1 << 20):.1f} "
                 f"MiB exceeds the {vmem_budget_mib:.0f} MiB budget "
-                f"(bm={c.block_m}, bk={c.block_k}, bn={c.block_n}); shrink "
+                f"(bm={bm}, bk={c.block_k}, bn={bn}); shrink "
                 "the block sizes",
                 site=s.path))
         if s.config.interpret is None and auto_interpret(s.config):

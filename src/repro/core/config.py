@@ -90,7 +90,10 @@ class DaismConfig:
     # Pallas tiling knobs (block sizes for the kernel); defaults chosen so the
     # working set fits a 16 MiB VMEM budget with headroom (see kernels/).
     # bm=32 relies on the fused shift-plane sweep: the kernel's peak live
-    # intermediate is (K_FUSE, bm, bn), not (bm, bk, bn).
+    # intermediate is (K_FUSE, bm, bn), not (bm, bk, bn). block_m is the
+    # upper bound of the M tile: fewer rows take a tile of M rounded up to
+    # 8 (kernels.ops.row_tile), and block_n is the N tile at a full M tile,
+    # widened as far as the M tile shrank (kernels.ops.col_tile).
     block_m: int = 32
     block_n: int = 128
     block_k: int = 128

@@ -22,6 +22,12 @@ the M tile rises 8 -> 32 (4x fewer grid steps) while peak VMEM stays
 ~ 3 * 32*8*128 * 4 B of live slab temporaries + tiles ≈ 0.5 MiB —
 comfortable within a 16 MiB VMEM budget, with MXU-aligned
 (multiple-of-128) N/K tile edges for the exact-baseline comparison kernel.
+bm=32 is the upper bound of the M tile: the ops.py wrapper fits it to M
+(``ops.row_tile``: 8 rows for a 4-row decode), since every emulated
+product costs the same whether its row is live or padding, and widens the
+N tile as far as the M tile shrank (``ops.col_tile``: 512 at 8 rows), so
+the slab, over which each K sub-chunk's fixed cost is spread, keeps its
+size.
 
 Validated in interpret mode on CPU against kernels/ref.py (bit-exact
 per-element products; f32 accumulation-order tolerance).

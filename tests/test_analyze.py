@@ -100,6 +100,16 @@ def test_tiling_padding_and_vmem_warnings():
     assert {"TIL001", "TIL002"} <= found
 
 
+def test_tiling_padding_reports_the_fitted_row_tile():
+    # one sequence of 4 tokens: every GEMM site has 4 rows, which the
+    # kernel pads to an 8-row tile, not to block_m = 32
+    graph = trace_site_graph(smoke_lm(), "*=pc3_tr:pallas", seq=4)
+    til001 = [f for f in check_tiling(graph) if f.code == "TIL001"]
+    assert til001
+    assert all("m: 4 -> 8" in f.message for f in til001)
+    assert not any("-> 32" in f.message for f in til001)
+
+
 def test_tiling_interpret_fallback_info_on_cpu():
     graph = trace_site_graph(smoke_lm(), "*=pc3_tr:pallas")
     til = check_tiling(graph)

@@ -5,8 +5,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.bitops import round_up
 from repro.core.config import Backend, DaismConfig, Variant
-from repro.kernels.ops import daism_matmul_pallas
+from repro.kernels.daism_matmul import daism_matmul_kernel
+from repro.kernels.ops import col_tile, daism_matmul_pallas, row_tile
 from repro.kernels.ref import daism_matmul_ref
 
 VARIANTS = [Variant.FLA, Variant.HLA, Variant.PC2, Variant.PC3,
@@ -61,6 +63,38 @@ def test_block_shape_invariance():
         outs.append(np.asarray(daism_matmul_pallas(a, w, cfg)))
     for o in outs[1:]:
         np.testing.assert_allclose(o, outs[0], rtol=1e-5, atol=1e-4)
+
+
+@pytest.mark.parametrize("m,block_m,want", [
+    (1, 32, 8), (4, 32, 8), (17, 32, 24), (32, 32, 32), (33, 32, 32),
+    (128, 32, 32), (128, 16, 16), (4, 4, 4)])
+def test_row_tile_fits_m_under_block_m(m, block_m, want):
+    assert row_tile(m, block_m) == want
+
+
+@pytest.mark.parametrize("n,bm,want", [
+    (24576, 8, 512), (24576, 16, 256), (24576, 24, 128), (24576, 32, 128),
+    (384, 8, 384),      # 3 blocks of 128: widened 3x, not 4x
+    (640, 8, 128),      # 5 blocks: no width above 1 divides it
+    (33, 8, 128)])      # N pads to one block_n, as at a full row tile
+def test_col_tile_widens_as_far_as_the_row_tile_shrank(n, bm, want):
+    assert col_tile(n, bm, 32, 128) == want
+
+
+@pytest.mark.parametrize("m", [1, 4, 7, 8, 9, 17, 31, 32, 33, 128])
+def test_row_tile_is_bitwise_equal_to_full_block(m):
+    """Tiles fitted to M change no output bit: the K order of the
+    accumulation is the one of a (32, 128) tile on the 32-row padded
+    input. N = 512 lets the N tile widen to 512 (M <= 8) and 256
+    (M <= 16)."""
+    k, n = 256, 512
+    a, w = _data(m, k, n, seed=4)
+    cfg = DaismConfig(variant=Variant.PC3_TR, backend=Backend.PALLAS)
+    got = np.asarray(daism_matmul_pallas(a, w, cfg))
+    a_p = jnp.pad(a, ((0, round_up(m, 32) - m), (0, 0)))
+    full = daism_matmul_kernel(a_p, w, variant=Variant.PC3_TR, block_m=32,
+                               block_k=128, block_n=128)
+    np.testing.assert_array_equal(got, np.asarray(full)[:m])
 
 
 def test_zero_padding_is_semantics_preserving():

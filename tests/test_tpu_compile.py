@@ -8,6 +8,7 @@ compiled program holds the custom kernel. Nothing runs, so they say
 nothing about results or speed.
 """
 import contextlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -87,10 +88,37 @@ def test_flash_attention_compiles(one_chip, variant):
     assert "%flash_attention" in text
 
 
-def test_daism_matmul_pallas_compiles_padded_decode(one_chip):
-    """The padding wrapper at a 4-row decode batch (M padded to block_m)."""
+def _compiled_pallas(sharding, m, k, n):
     cfg = DaismConfig(variant=Variant.PC3_TR, backend=Backend.PALLAS,
                       interpret=False)
-    text = _compiled_text(lambda a, w: daism_matmul_pallas(a, w, cfg),
-                          one_chip, (4, D_MODEL), (D_MODEL, D_FF))
+    return _compiled_text(lambda a, w: daism_matmul_pallas(a, w, cfg),
+                          sharding, (m, k), (k, n))
+
+
+def _kernel_rows(text):
+    """Row counts of the kernel outputs in a compiled program."""
+    return {int(r) for r in re.findall(r"f32\[(\d+),\d+\][^\n]*custom-call",
+                                       text)}
+
+
+def test_daism_matmul_pallas_compiles_padded_decode(one_chip):
+    """The padding wrapper at a 4-row decode batch: M padded to the 8-row
+    tile ``row_tile`` fits to it, not to block_m = 32."""
+    text = _compiled_pallas(one_chip, 4, D_MODEL, D_FF)
     assert "tpu_custom_call" in text
+    assert _kernel_rows(text) == {8}
+
+
+# StarCoder2-15B's decode GEMMs: MLP up, MLP down, lm_head.
+SC2_KN = ((6144, 24576), (24576, 6144), (6144, 49152))
+
+
+@pytest.mark.parametrize("m,rows", [(4, 8), (12, 16)])
+@pytest.mark.parametrize("k,n", SC2_KN, ids=[f"{k}x{n}" for k, n in SC2_KN])
+def test_daism_matmul_pallas_fits_row_tile_at_sc2_widths(one_chip, k, n, m,
+                                                         rows):
+    """Row tiles of 8 and 16, under the N tile ``col_tile`` widens to
+    match (512 and 256), compile at StarCoder2-15B widths."""
+    text = _compiled_pallas(one_chip, m, k, n)
+    assert "tpu_custom_call" in text
+    assert _kernel_rows(text) == {rows}
