@@ -92,10 +92,13 @@ class DaismConfig:
     # bm=32 relies on the fused shift-plane sweep: the kernel's peak live
     # intermediate is (K_FUSE, bm, bn), not (bm, bk, bn). block_m is the
     # upper bound of the M tile: fewer rows take a tile of M rounded up to
-    # 8 (kernels.ops.row_tile), and block_n is the N tile at a full M tile,
-    # widened as far as the M tile shrank (kernels.ops.col_tile).
+    # 8 (kernels.ops.row_tile). block_n caps the N tile at a full M tile,
+    # and the cap widens as far as the M tile shrank (kernels.ops.col_tile:
+    # 512 at 32 rows, 2048 at 8); N still pads to 128 lanes only. A wide
+    # N tile spreads each K sub-chunk's fixed cost over more lanes
+    # (kernels/daism_matmul.py gives the v5e timings).
     block_m: int = 32
-    block_n: int = 128
+    block_n: int = 512
     block_k: int = 128
     interpret: Optional[bool] = None  # None -> auto (True on CPU)
     attn_kernel: str = "jnp"  # 'jnp' | 'flash' (attention-score sites only)

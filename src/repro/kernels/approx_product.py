@@ -28,8 +28,11 @@ from repro.core.config import Variant
 _BIAS = 127
 
 # K-dim sub-chunk width of the fused plane sweep. 8 keeps the live
-# (K_FUSE, bm, bn) slabs at VPU-sublane granularity: with bm = bn = 128 the
+# (K_FUSE, bm, bn) slabs at VPU-sublane granularity: at the GEMM's
+# (32, 512) and (8, 2048) tiles a slab holds 8 x 16384 elements, and the
 # ~3 live int32/f32 temporaries total ~1.5 MiB, independent of block_k.
+# Each sub-chunk also pays a fixed cost (the a-fields' transpose and lane
+# broadcast), which a wider bn spreads over more lanes.
 K_FUSE = 8
 
 

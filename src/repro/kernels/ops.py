@@ -14,6 +14,8 @@ from .daism_matmul import daism_matmul_kernel
 
 # Row granularity of the M tile: the f32 output tile's sublane count.
 _ROW_ALIGN = 8
+# Lane granularity of the N tile: N pads to a multiple of this, and no more.
+_LANES = 128
 
 
 def row_tile(m: int, block_m: int) -> int:
@@ -29,20 +31,21 @@ def row_tile(m: int, block_m: int) -> int:
 
 
 def col_tile(n: int, bm: int, block_m: int, block_n: int) -> int:
-    """The kernel's N tile under an M tile of ``bm`` rows: ``block_n``,
-    widened by up to ``block_m // bm`` times.
+    """The kernel's N tile under an M tile of ``bm`` rows: the widest
+    multiple of 128 lanes that divides N's 128-padded extent, capped at
+    ``block_n`` widened ``block_m // bm`` times.
 
     Each K sub-chunk of the sweep pays a fixed cost (its operand
-    transposes and broadcasts) besides its (K_FUSE, bm, bn) slab, so a
-    shorter row tile takes a wider column tile and the slab keeps the size
-    of a full one. The width divides N's ``block_n``-padded extent, so N
-    pads no further than at ``block_n``.
+    transposes and broadcasts) besides its (K_FUSE, bm, bn) slab, so the
+    wider the tile, the more lanes share that cost; a shorter row tile
+    takes a wider column tile under the same slab size. N pads only to
+    128 lanes, so a narrow N keeps a narrow tile.
     """
-    blocks = _round_up(n, block_n) // block_n
-    widen = block_m // bm
-    while blocks % widen:
+    lanes = _round_up(n, _LANES) // _LANES
+    widen = max(1, min(lanes, block_n * (block_m // bm) // _LANES))
+    while lanes % widen:
         widen -= 1
-    return block_n * widen
+    return _LANES * widen
 
 
 @functools.partial(jax.jit, static_argnums=(2,))
@@ -50,9 +53,10 @@ def daism_matmul_pallas(a: jnp.ndarray, w: jnp.ndarray, cfg: DaismConfig) -> jnp
     """(M, K) @ (K, N) -> (M, N) f32 with automatic pad-to-tile.
 
     The M tile is :func:`row_tile` of M (``cfg.block_m`` is its upper
-    bound) and the N tile :func:`col_tile` (``cfg.block_n`` at a full row
-    tile); K uses ``cfg.block_k``. Zero padding is semantics-preserving:
-    approx(0 * w) == 0 contributes nothing to the exact accumulation.
+    bound) and the N tile :func:`col_tile` (``cfg.block_n`` caps it at a
+    full row tile); K uses ``cfg.block_k``. Zero padding is
+    semantics-preserving: approx(0 * w) == 0 contributes nothing to the
+    exact accumulation.
     """
     if a.dtype != jnp.bfloat16 or w.dtype != jnp.bfloat16:
         raise ValueError("Pallas DAISM kernel is bfloat16-only; f32 uses the "

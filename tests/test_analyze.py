@@ -91,9 +91,11 @@ def test_tiling_padding_and_vmem_warnings():
     # spec grammar has no block syntax: build the policy programmatically.
     # The fused plane sweep keeps the live slabs at (K_FUSE, bm, bn), so
     # only very large tiles blow the budget — block_k enters through the
-    # staged int32 fields and the streamed bf16 tiles.
+    # staged int32 fields and the streamed bf16 tiles. N pads to 128 lanes
+    # whatever block_n is, so the smoke model's narrow N gives bn = 128 and
+    # block_k alone has to carry the footprint past 16 MiB.
     bad = DaismConfig(variant=Variant.PC3_TR, backend=Backend.PALLAS,
-                      block_m=2048, block_n=1000, block_k=2048)
+                      block_m=2048, block_n=1000, block_k=16384)
     pol = ApproxPolicy(rules=(Rule("*/ffn/*", bad),), default=EXACT)
     graph = trace_site_graph(smoke_lm(), pol)
     found = codes(check_tiling(graph))
@@ -108,6 +110,18 @@ def test_tiling_padding_reports_the_fitted_row_tile():
     assert til001
     assert all("m: 4 -> 8" in f.message for f in til001)
     assert not any("-> 32" in f.message for f in til001)
+
+
+@pytest.mark.parametrize("rows", [8, 128], ids=["decode", "prefill"])
+def test_default_tiles_fit_vmem_at_sc2_widths(rows):
+    """StarCoder2-15B's GEMMs under the default tiles ((8, 2048) at 8
+    rows, (32, 512) at 128) stay within the VMEM budget, and K and N pad
+    nowhere."""
+    cfg = dataclasses.replace(get_config("starcoder2_15b"), n_layers=1)
+    graph = trace_site_graph(cfg, "*=pc3_tr:pallas", seq=rows)
+    assert {s.dims[0] for s in graph.sites if not s.config.exact} == {rows}
+    found = codes(check_tiling(graph))
+    assert not {"TIL001", "TIL002"} & found
 
 
 def test_tiling_interpret_fallback_info_on_cpu():

@@ -81,12 +81,33 @@ def test_col_tile_widens_as_far_as_the_row_tile_shrank(n, bm, want):
     assert col_tile(n, bm, 32, 128) == want
 
 
+@pytest.mark.parametrize("n,bm,want", [
+    (6144, 32, 512), (24576, 32, 512), (49152, 32, 512), (512, 32, 512),
+    (6144, 8, 2048), (24576, 8, 2048), (49152, 8, 2048), (512, 8, 512)])
+def test_col_tile_at_the_default_cap(n, bm, want):
+    """StarCoder2-15B's GEMM widths at the default ``block_n``: 512 lanes
+    at a full row tile (prefill), 2048 at 8 rows (decode), and no wider
+    than N's own 512."""
+    cfg = DaismConfig()
+    assert col_tile(n, bm, cfg.block_m, cfg.block_n) == want
+
+
+@pytest.mark.parametrize("bm", [8, 16, 32])
+@pytest.mark.parametrize("n", [33, 64, 640])
+def test_col_tile_pads_n_to_128_lanes_only(n, bm):
+    """A narrow or ragged N keeps a tile within its 128-padded extent, so
+    N pads no further than to 128 lanes whatever the cap."""
+    cfg = DaismConfig()
+    bn = col_tile(n, bm, cfg.block_m, cfg.block_n)
+    assert bn % 128 == 0 and round_up(n, 128) % bn == 0
+
+
 @pytest.mark.parametrize("m", [1, 4, 7, 8, 9, 17, 31, 32, 33, 128])
 def test_row_tile_is_bitwise_equal_to_full_block(m):
     """Tiles fitted to M change no output bit: the K order of the
     accumulation is the one of a (32, 128) tile on the 32-row padded
-    input. N = 512 lets the N tile widen to 512 (M <= 8) and 256
-    (M <= 16)."""
+    input. At the default cap N = 512 runs on 512-wide tiles at every
+    M."""
     k, n = 256, 512
     a, w = _data(m, k, n, seed=4)
     cfg = DaismConfig(variant=Variant.PC3_TR, backend=Backend.PALLAS)
@@ -95,6 +116,21 @@ def test_row_tile_is_bitwise_equal_to_full_block(m):
     full = daism_matmul_kernel(a_p, w, variant=Variant.PC3_TR, block_m=32,
                                block_k=128, block_n=128)
     np.testing.assert_array_equal(got, np.asarray(full)[:m])
+
+
+def test_prefill_tile_is_bitwise_equal_to_narrow_tile():
+    """A 128-row GEMM at the default config runs (32, 512) tiles, bitwise
+    equal to the (32, 128, 128) kernel: the N tile does not change the K
+    order of the accumulation."""
+    m, k, n = 128, 256, 1024
+    a, w = _data(m, k, n, seed=5)
+    cfg = DaismConfig(variant=Variant.PC3_TR, backend=Backend.PALLAS)
+    assert col_tile(n, row_tile(m, cfg.block_m), cfg.block_m,
+                    cfg.block_n) == 512
+    got = np.asarray(daism_matmul_pallas(a, w, cfg))
+    narrow = daism_matmul_kernel(a, w, variant=Variant.PC3_TR, block_m=32,
+                                 block_k=128, block_n=128)
+    np.testing.assert_array_equal(got, np.asarray(narrow))
 
 
 def test_zero_padding_is_semantics_preserving():

@@ -111,6 +111,8 @@ def test_daism_matmul_pallas_compiles_padded_decode(one_chip):
 
 # StarCoder2-15B's decode GEMMs: MLP up, MLP down, lm_head.
 SC2_KN = ((6144, 24576), (24576, 6144), (6144, 49152))
+# ... and its prefill GEMMs: those, the attention's q/o and k/v.
+SC2_PREFILL_KN = SC2_KN + ((6144, 6144), (6144, 512))
 
 
 @pytest.mark.parametrize("m,rows", [(4, 8), (12, 16)])
@@ -118,7 +120,18 @@ SC2_KN = ((6144, 24576), (24576, 6144), (6144, 49152))
 def test_daism_matmul_pallas_fits_row_tile_at_sc2_widths(one_chip, k, n, m,
                                                          rows):
     """Row tiles of 8 and 16, under the N tile ``col_tile`` widens to
-    match (512 and 256), compile at StarCoder2-15B widths."""
+    match (2048 and 1024 where N allows), compile at StarCoder2-15B
+    widths."""
     text = _compiled_pallas(one_chip, m, k, n)
     assert "tpu_custom_call" in text
     assert _kernel_rows(text) == {rows}
+
+
+@pytest.mark.parametrize("k,n", SC2_PREFILL_KN,
+                         ids=[f"{k}x{n}" for k, n in SC2_PREFILL_KN])
+def test_daism_matmul_pallas_compiles_prefill_at_sc2_widths(one_chip, k, n):
+    """A 128-row prefill GEMM (4 slots x chunk 32) runs (32, 512) tiles,
+    which compile at StarCoder2-15B widths."""
+    text = _compiled_pallas(one_chip, 128, k, n)
+    assert "tpu_custom_call" in text
+    assert _kernel_rows(text) == {128}
