@@ -1,139 +1,27 @@
-"""Benchmark harness: one module per paper table/figure (DESIGN.md section 6).
+"""Paper-table harness: one module per paper table or figure.
 
-Prints ``name,us_per_call,derived`` CSV rows plus per-benchmark claim
-checks. Each suite's rows + claims are written to ``BENCH_<name>.json`` at
-the repo root (``serve_bench`` -> ``BENCH_serve.json``) — small checked-in
-artifacts a reviewer can diff without rerunning the suite — and the
-combined results go to results/benchmarks.json. The dry-run/roofline
-tables (EXPERIMENTS.md Dry-run/Roofline) come from ``repro.launch.dryrun``,
-which needs the 512-device environment and is run separately.
+Prints ``name,us_per_call,derived`` CSV rows plus per-module claim checks.
+These modules reproduce the paper's figures and tables from analytical
+models and accuracy runs; they are not speed measurements. Speed is
+measured on the chip by ``bench/run.py`` (cells in ``BENCHMARK.json``).
+The dry-run/roofline tables come from ``repro.launch.dryrun``, which needs
+the 512-device environment and is run separately.
 
-``--check`` turns the harness into a regression gate: instead of writing
-artifacts it re-runs each suite fresh and compares the claims a suite
-names in its module-level ``REGRESSION_CLAIMS`` dict against the
-checked-in ``BENCH_<name>.json``. A named claim that moved >20% in the
-bad direction ("higher"/"lower" = which way is better), or a boolean
-claim that held in the artifact but fails fresh, exits 1. Artifacts whose
-recorded platform differs from the current runtime are skipped with a
-notice (a CPU CI run cannot invalidate a TPU artifact), so the gate is
-safe to wire into CI unconditionally.
+    PYTHONPATH=src:. python benchmarks/run.py [module ...]
 """
 from __future__ import annotations
 
 import json
-import os
-import subprocess
 import sys
 import time
 
-_MODULES = ("error_distance", "energy", "arch_cycles", "gemm_bench",
-            "attn_bench", "accuracy", "policy_sweep", "serve_bench")
-
-_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _artifact_path(name: str) -> str:
-    short = name[:-len("_bench")] if name.endswith("_bench") else name
-    return os.path.join(_REPO_ROOT, f"BENCH_{short}.json")
-
-
-def _meta() -> dict:
-    """Provenance stamp: which code/runtime produced the artifact, so
-    cross-PR perf trajectories are comparable (and non-comparable runs —
-    different device counts, jax versions — are visibly so)."""
-    try:
-        sha = subprocess.run(["git", "rev-parse", "--short", "HEAD"],
-                             cwd=_REPO_ROOT, capture_output=True, text=True,
-                             timeout=10).stdout.strip() or "unknown"
-    except OSError:
-        sha = "unknown"
-    import jax
-
-    return {"git_sha": sha, "jax": jax.__version__,
-            "devices": jax.device_count(),
-            "platform": jax.default_backend()}
-
-
-# --check regression tolerance: a named numeric claim may move up to this
-# fraction in the bad direction before the gate fails (absorbs smoke-run
-# noise on shared CI machines; real regressions from e.g. a lost kernel
-# fusion or a broken speculative accept path move far more than 20%)
-_CHECK_TOLERANCE = 0.20
-
-
-def _check(only) -> None:
-    """Compare fresh claims against checked-in artifacts; exit 1 on a >20%
-    regression of any claim named in a suite's ``REGRESSION_CLAIMS``."""
-    meta = _meta()
-    failures, notices = [], []
-    for name in only:
-        mod = __import__(f"benchmarks.{name}", fromlist=["run"])
-        spec = getattr(mod, "REGRESSION_CLAIMS", None)
-        if not spec:
-            notices.append(f"{name}: no REGRESSION_CLAIMS declared, skipped")
-            continue
-        path = _artifact_path(name)
-        if not os.path.exists(path):
-            notices.append(f"{name}: no checked-in artifact at {path}, "
-                           "skipped")
-            continue
-        with open(path) as f:
-            artifact = json.load(f)
-        old_platform = artifact.get("meta", {}).get("platform")
-        if old_platform != meta["platform"]:
-            notices.append(
-                f"{name}: artifact platform {old_platform!r} != current "
-                f"{meta['platform']!r}, skipped (not comparable)")
-            continue
-        t0 = time.perf_counter()
-        _, fresh = mod.run()
-        dt = time.perf_counter() - t0
-        print(f"# check {name} ({dt:.1f}s)", flush=True)
-        baseline = artifact.get("claims", {})
-        for key, direction in spec.items():
-            if key not in baseline:
-                notices.append(f"{name}.{key}: not in artifact (new claim), "
-                               "skipped")
-                continue
-            if key not in fresh:
-                failures.append(f"{name}.{key}: claim vanished from suite")
-                continue
-            old, new = baseline[key], fresh[key]
-            if isinstance(old, bool) or isinstance(new, bool):
-                if old is True and new is not True:
-                    failures.append(f"{name}.{key}: held in artifact, "
-                                    f"now {new}")
-                continue
-            old, new = float(old), float(new)
-            worse = (new < old * (1 - _CHECK_TOLERANCE)
-                     if direction == "higher"
-                     else new > old * (1 + _CHECK_TOLERANCE))
-            status = "REGRESSED" if worse else "ok"
-            print(f"check,{name}.{key},{old} -> {new},{status}", flush=True)
-            if worse:
-                failures.append(
-                    f"{name}.{key}: {old} -> {new} "
-                    f"({direction} is better, tolerance "
-                    f"{_CHECK_TOLERANCE:.0%})")
-    for n in notices:
-        print(f"# notice: {n}")
-    if failures:
-        print(f"# {len(failures)} regression(s):")
-        for f_ in failures:
-            print(f"#   {f_}")
-        raise SystemExit(1)
-    print("# regression gate: all named claims within tolerance")
+_MODULES = ("error_distance", "energy", "arch_cycles", "accuracy",
+            "policy_sweep")
 
 
 def main() -> None:
-    argv = sys.argv[1:]
-    if "--check" in argv:
-        argv.remove("--check")
-        _check(argv or _MODULES)
-        return
-    only = argv or _MODULES
-    meta = _meta()
-    all_rows, all_claims = [], {}
+    only = sys.argv[1:] or _MODULES
+    all_claims = {}
     for name in only:
         mod = __import__(f"benchmarks.{name}", fromlist=["run"])
         t0 = time.perf_counter()
@@ -147,17 +35,7 @@ def main() -> None:
                   flush=True)
         for k, v in claims.items():
             print(f"claim,{name}.{k},{v}", flush=True)
-        with open(_artifact_path(name), "w") as f:
-            json.dump({"suite": name, "elapsed_s": round(dt, 1),
-                       "meta": meta, "rows": rows, "claims": claims}, f,
-                      indent=1, default=str)
-            f.write("\n")
-        all_rows += rows
         all_claims.update({f"{name}.{k}": v for k, v in claims.items()})
-    os.makedirs("results", exist_ok=True)
-    with open("results/benchmarks.json", "w") as f:
-        json.dump({"meta": meta, "rows": all_rows, "claims": all_claims},
-                  f, indent=1, default=str)
     failed = [k for k, v in all_claims.items() if v is False]
     print(f"# claims: {sum(1 for v in all_claims.values() if v is True)} "
           f"hold, {len(failed)} failed: {failed}")

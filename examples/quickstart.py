@@ -21,7 +21,7 @@ rng = np.random.default_rng(0)
 a = jnp.asarray(rng.normal(size=(8, 64)), jnp.bfloat16)
 b = jnp.asarray(rng.normal(size=(64, 8)), jnp.bfloat16)
 exact = np.asarray(a, np.float32) @ np.asarray(b, np.float32)
-for backend in (Backend.JNP, Backend.LUT, Backend.PALLAS):
+for backend in (Backend.JNP, Backend.PALLAS):
     cfg = DaismConfig(variant=Variant.PC3_TR, backend=backend)
     out = np.asarray(daism_matmul(a, b, cfg))
     rel = np.abs(out - exact).mean() / np.abs(exact).mean()

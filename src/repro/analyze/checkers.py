@@ -383,9 +383,9 @@ def _check_spec_draft(graph: SiteGraph, engine_cfg, *,
     * a windowed model — draft steps write K/V ``spec_k`` positions ahead
       of the committed length, and a rolling ring buffer can wrap those
       writes onto live history before verify overwrites them;
-    * the draft tier is illegal for the model's compute dtype (LUT backend
-      or flash-attention DAISM variants off bf16) — the draft jit would
-      raise at the first speculative step, long after launch;
+    * the draft tier is illegal for the model's compute dtype (Pallas
+      backend or flash-attention DAISM variants off bf16) — the draft jit
+      would raise at the first speculative step, long after launch;
     * the draft policy is not actually cheaper than the target under the
       analyzer's energy model — speculation then burns more multiply
       energy per accepted token than plain decode, silently.

@@ -44,7 +44,6 @@ class Backend(str, enum.Enum):
     """Execution strategy for the approximate GEMM."""
 
     JNP = "jnp"              # pure-jnp vectorized bit ops (reference / oracle)
-    LUT = "lut"              # bf16-only: 256x256 precomputed mantissa-product table
     PALLAS = "pallas"        # Pallas TPU kernel (interpret=True on CPU)
     EXACT = "exact"          # plain MXU matmul (deployment path)
 

@@ -9,7 +9,6 @@ Backends
   * ``jnp``    — vectorized bit ops, K-chunked to bound the (M, Kc, N)
                  intermediate. The reference semantics; differentiable via
                  ``custom_vjp``.
-  * ``lut``    — bf16 gather fast path (bit-identical, see core/lut.py).
   * ``pallas`` — VMEM-tiled TPU kernel (kernels/daism_matmul.py).
   * ``exact``  — plain MXU matmul (deployment path).
 
@@ -21,7 +20,6 @@ can be *trained* under the approximation).
 from __future__ import annotations
 
 import functools
-from typing import Callable
 
 import jax
 import jax.numpy as jnp
@@ -30,13 +28,6 @@ from jax import lax
 from .bitops import round_up as _round_up
 from .config import Backend, DaismConfig, Variant
 from .floatmul import approx_mul_to_f32
-from .lut import approx_mul_to_f32_lut
-
-
-def _product_fn(cfg: DaismConfig) -> Callable:
-    if cfg.backend is Backend.LUT:
-        return functools.partial(approx_mul_to_f32_lut, variant=cfg.variant)
-    return functools.partial(approx_mul_to_f32, variant=cfg.variant)
 
 
 def _matmul_chunked(a: jnp.ndarray, w: jnp.ndarray, cfg: DaismConfig) -> jnp.ndarray:
@@ -44,7 +35,7 @@ def _matmul_chunked(a: jnp.ndarray, w: jnp.ndarray, cfg: DaismConfig) -> jnp.nda
     m, k = a.shape
     k2, n = w.shape
     assert k == k2, (a.shape, w.shape)
-    prod = _product_fn(cfg)
+    prod = functools.partial(approx_mul_to_f32, variant=cfg.variant)
     kc = min(cfg.k_chunk, k)
     k_pad = _round_up(k, kc)
     if k_pad != k:  # zero-padding is exact: approx(0 * w) == 0

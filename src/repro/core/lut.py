@@ -1,13 +1,10 @@
-"""bf16 mantissa-product lookup table — a TPU-native fast path (beyond-paper).
+"""bf16 mantissa-product table and the calibration statistic read from it.
 
 For bfloat16, the effective mantissa is 8 bits with the MSB always set, so
 there are only 128 x 128 = 16,384 distinct mantissa pairs. The full 16-bit
-approximate product for every pair is precomputed once per variant into a
-32 KiB int32 table — small enough to live in VMEM — turning the 8-step
-shift/OR chain into a single gather. This is the SRAM "pre-computed line"
-idea (paper §3.3) taken to its logical limit on TPU: the entire approximate
-multiplication becomes one table read, mirroring DAISM's one-SRAM-read
-property. Numerics are bit-identical to the jnp path (asserted in tests).
+approximate product for every pair is computed once per variant into a
+(128, 128) int32 table; ``shrinkage_factor`` averages it into the GEMM's
+calibration constant (``DaismConfig.calibrated``).
 """
 from __future__ import annotations
 
@@ -16,11 +13,8 @@ import functools
 import jax.numpy as jnp
 import numpy as np
 
-from . import bitops
 from .config import Variant
 from .multiplier import approx_mul_uint
-
-_BIAS = 127
 
 
 @functools.lru_cache(maxsize=None)
@@ -47,35 +41,7 @@ def shrinkage_factor(variant: Variant) -> float:
     cuts mean GEMM error ~2x for FLA and improves end-to-end logit fidelity
     (tests/test_gemm.py::test_calibration_reduces_bias).
     """
-    import jax
-
     t = mantissa_product_table(Variant(variant)).astype(np.float64)
     mw = np.arange(128, 256, dtype=np.float64)[:, None]
     mx = np.arange(128, 256, dtype=np.float64)[None, :]
     return float((t / (mw * mx)).mean())
-
-
-def approx_mul_to_f32_lut(x: jnp.ndarray, w: jnp.ndarray, variant: Variant) -> jnp.ndarray:
-    """bf16-only elementwise approximate product via table gather -> f32.
-
-    Bit-identical to ``floatmul.approx_mul_to_f32`` for bfloat16 operands.
-    """
-    if x.dtype != jnp.bfloat16 or w.dtype != jnp.bfloat16:
-        raise ValueError("LUT path is bfloat16-only")
-    table = jnp.asarray(mantissa_product_table(Variant(variant)))
-    sx, ex, mx = bitops.decompose(x)
-    sw, ew, mw = bitops.decompose(w)
-    sx, ex, mx, sw, ew, mw = jnp.broadcast_arrays(sx, ex, mx, sw, ew, mw)
-
-    idx = (jnp.maximum(mw - 128, 0) << 7) | jnp.maximum(mx - 128, 0)
-    prod = jnp.take(table.reshape(-1), idx)
-    top = (prod >> 15) & 1
-    man = jnp.where(top == 1, prod >> 8, prod >> 7) & 0xFF
-
-    sign = sx ^ sw
-    exp = ex + ew - _BIAS + top
-    man_f32 = man << 16
-    zero = (mx == 0) | (mw == 0)
-    exp = jnp.where(zero, 0, exp)
-    man_f32 = jnp.where(zero, 0, man_f32)
-    return bitops.compose_f32(sign, exp, man_f32)

@@ -14,7 +14,7 @@ named per-request tiers and spreads the workload across them (mixed-tier
 traffic batches per resolved policy — no cross-tier recompiles). The legacy
 ``--variant pc3_tr`` flag still works through the uniform-policy
 deprecation shim. After the run the per-group site resolution report is
-printed. See benchmarks/serve_bench.py for numbers.
+printed. Speed is measured on the chip by bench/run.py.
 """
 import argparse
 import dataclasses

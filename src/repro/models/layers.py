@@ -329,20 +329,9 @@ def self_attention(ctx: Ctx, x: jnp.ndarray, cfg: ArchConfig, *,
         ck, cv, pos = cache["k"], cache["v"], cache["pos"]
         size = ck.shape[1]
         ring = "abs_pos" in cache
-        if jnp.ndim(pos) == 1:  # per-slot cache: row i writes at pos[i]
-            if ring:
-                raise NotImplementedError(
-                    "per-slot caches do not support ring/window buffers")
-            row_update = jax.vmap(
-                lambda cr, kr, p: lax.dynamic_update_slice(cr, kr, (p, 0, 0)))
-            ck = row_update(ck, k.astype(ck.dtype), pos)
-            cv = row_update(cv, v.astype(cv.dtype), pos)
-        else:
-            slot = lax.rem(pos, size) if ring else pos
-            ck = lax.dynamic_update_slice(ck, k.astype(ck.dtype),
-                                          (0, slot, 0, 0))
-            cv = lax.dynamic_update_slice(cv, v.astype(cv.dtype),
-                                          (0, slot, 0, 0))
+        slot = lax.rem(pos, size) if ring else pos
+        ck = lax.dynamic_update_slice(ck, k.astype(ck.dtype), (0, slot, 0, 0))
+        cv = lax.dynamic_update_slice(cv, v.astype(cv.dtype), (0, slot, 0, 0))
         ck = constrain(ck, ("cache_batch", "cache_seq", "act_kv_heads", None))
         cv = constrain(cv, ("cache_batch", "cache_seq", "act_kv_heads", None))
         new_cache = dict(k=ck, v=cv, pos=pos + s)

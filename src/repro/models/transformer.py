@@ -451,8 +451,8 @@ class DecoderLM:
                         positions, pos,
                         image_embeds: Optional[jnp.ndarray] = None):
         """Embed -> cached layer stack -> logits. ``positions`` feeds rope and
-        attention masking; ``pos`` is the cache write offset — a scalar
-        (shared, legacy) or a (B,) vector (per-slot serving cache)."""
+        attention masking; ``pos`` is the cache's scalar write offset,
+        shared by every row (the engine serves from the paged cache)."""
         cfg = self.cfg
         ctx = Ctx("apply", params=params)
 
@@ -502,12 +502,9 @@ class DecoderLM:
                     image_embeds: Optional[jnp.ndarray] = None):
         """tokens: (B, 1). Returns (logits (B, 1, V), new cache).
 
-        ``cache['pos']`` is a scalar (all rows at the same offset — the
-        legacy single-request path) or a (B,) vector (slot cache: row i is
-        an independent request at offset pos[i], see repro.serve)."""
+        ``cache['pos']`` is a scalar: all rows sit at the same offset."""
         pos = cache["pos"]
-        positions = pos[:, None] if jnp.ndim(pos) == 1 else jnp.reshape(
-            pos, (1,))
+        positions = jnp.reshape(pos, (1,))
         logits, new_lc = self._cached_forward(params, tokens, cache,
                                               positions, pos, image_embeds)
         return logits, dict(new_lc, pos=pos + 1)
@@ -521,9 +518,7 @@ class DecoderLM:
         overwritten by decode before position p is reached or excluded by
         the causal mask, so it is never attended.
 
-        Returns (logits (B, S, V), new cache with pos advanced by S). A
-        serving engine overwrites ``pos`` with per-row true lengths when it
-        adopts the K/V into its slot pool.
+        Returns (logits (B, S, V), new cache with pos advanced by S).
         """
         if "abs_pos" in cache:
             raise NotImplementedError(

@@ -86,12 +86,11 @@ def validate_for_dtype(cfg: DaismConfig, dtype, *, site: str = "") -> None:
             f"{where}DAISM approximate GEMMs support bfloat16/float32 "
             f"operands, got {name}; run this site exact or change the "
             "compute dtype")
-    if cfg.backend in (Backend.LUT, Backend.PALLAS) and name != "bfloat16":
+    if cfg.backend is Backend.PALLAS and name != "bfloat16":
         raise ValueError(
-            f"{where}backend {cfg.backend.value!r} is bfloat16-only "
-            f"(256x256 mantissa table / Pallas kernel), got {name}; use "
-            "backend='jnp' for float32 or switch the compute dtype to "
-            "bfloat16")
+            f"{where}backend {cfg.backend.value!r} is bfloat16-only, got "
+            f"{name}; use backend='jnp' for float32 or switch the compute "
+            "dtype to bfloat16")
 
 
 # ---------------------------------------------------------------------------

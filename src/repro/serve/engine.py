@@ -28,8 +28,7 @@ Design (PR 1 slot pool -> PR 6 paged pool -> this: sharded + async):
   admission + page reservation — step N+1's batch assembly) while the
   device chews, and only then blocks on the token fetch. Fetch-blocked time
   is accounted per run (``ServeReport.host_idle_frac``); ``overlap=False``
-  fetches immediately after each launch — the synchronous baseline the
-  idle-fraction claim in benchmarks/serve_bench.py is measured against.
+  fetches immediately after each launch (the synchronous loop).
 * **Preemption/swap** — ``preempt=True`` switches admission from
   whole-lifetime page reservation to optimistic prompt-only allocation
   with on-demand ``extend`` at every block boundary. Under page exhaustion

@@ -25,9 +25,8 @@ list of block ids — its block table — covering its logical positions
   still hit it, and the allocator reclaims evictable blocks (oldest first)
   only after the plain free list is exhausted.
 * **Fragmentation accounting** — ``stats`` / ``utilization`` report live
-  tokens vs. reserved cells vs. pool capacity, the numbers serve_bench.py
-  uses to demonstrate the paged pool's memory win over the slot pool
-  (a slot pool is the degenerate ``block_size == max_seq`` configuration).
+  tokens vs. reserved cells vs. pool capacity (the benchmark's
+  ``kv.util_peak_pct.serve`` reads ``utilization``).
 
 * **Speculative rollback** — speculative decoding writes ``k`` draft
   positions ahead of a sequence's committed length and may keep only a

@@ -53,7 +53,7 @@ def test_trace_site_graph_matches_runtime_segmentation():
 def test_trace_handles_illegal_candidate_policy():
     """Policies the ArchConfig would reject (bf16-only backend on an fp32
     model) still trace — legality is a finding, not a crash."""
-    graph = trace_site_graph(get_config("lenet5"), "*=pc3_tr:lut")
+    graph = trace_site_graph(get_config("lenet5"), "*=pc3_tr:pallas")
     assert graph.sites  # traced anyway
     bck = check_backend(graph)
     assert bck and all(f.code == "BCK001" and f.severity == "error"
@@ -285,12 +285,12 @@ def test_serving_spec_draft_srv009():
     assert any("window" in f.message and f.severity == "error"
                for f in found)
 
-    # dtype illegality: LUT draft on an f32 model
+    # dtype illegality: Pallas draft on an f32 model
     f32 = dataclasses.replace(smoke_lm(), compute_dtype="float32",
                               param_dtype="float32")
     fg = trace_site_graph(f32)
     found = [f for f in check_serving(
-        fg, EngineConfig(spec_draft="*=pc3_tr:lut", spec_k=3))
+        fg, EngineConfig(spec_draft="*=pc3_tr:pallas", spec_k=3))
         if f.code == "SRV009"]
     assert any(f.severity == "error" for f in found)
 

@@ -11,7 +11,7 @@ from hypothesis import given, settings, strategies as st
 from repro.core import Variant, approx_mul_to_f32
 from repro.core.bitops import (compose_bf16, compose_f32, decompose_bf16,
                                decompose_f32)
-from repro.core.lut import approx_mul_to_f32_lut
+from repro.core.lut import mantissa_product_table
 
 VARIANTS = [Variant.FLA, Variant.HLA, Variant.PC2, Variant.PC3,
             Variant.PC2_TR, Variant.PC3_TR]
@@ -58,13 +58,19 @@ def test_subnormal_flush():
     np.testing.assert_array_equal(out, 0.0)
 
 
-def test_lut_bit_identical(operands):
-    x = jnp.asarray(operands[0], jnp.bfloat16)
-    w = jnp.asarray(operands[1], jnp.bfloat16)
+def test_lut_bit_identical():
+    """``mantissa_product_table`` (what ``shrinkage_factor`` averages)
+    holds the product ``approx_mul_to_f32`` computes, for all 128 x 128
+    bf16 mantissa pairs in [1, 2), per variant."""
+    frac = np.arange(128) / 128
+    x = jnp.asarray(1 + frac[None, :], jnp.bfloat16)   # columns: mx
+    w = jnp.asarray(1 + frac[:, None], jnp.bfloat16)   # rows: mw
     for variant in VARIANTS:
-        a = np.asarray(approx_mul_to_f32(x, w, variant))
-        b = np.asarray(approx_mul_to_f32_lut(x, w, variant))
-        np.testing.assert_array_equal(a, b)
+        t = mantissa_product_table(variant).astype(np.int64)  # 16-bit
+        top = t >> 15                  # 1 where the product is in [2, 4)
+        want = (t >> (7 + top)) * 2.0 ** (top - 7)  # truncated to 8 bits
+        got = np.asarray(approx_mul_to_f32(x, w, variant))
+        np.testing.assert_array_equal(got, want)
 
 
 def test_exact_variant_is_exact(operands):

@@ -26,10 +26,9 @@ def test_k_chunk_invariance():
 def test_backends_agree():
     a, w = _ab(8, 64, 16, seed=1)
     cfgs = [DaismConfig(variant=Variant.PC3_TR, backend=b)
-            for b in (Backend.JNP, Backend.LUT, Backend.PALLAS)]
+            for b in (Backend.JNP, Backend.PALLAS)]
     outs = [np.asarray(daism_matmul(a, w, c)) for c in cfgs]
-    np.testing.assert_array_equal(outs[0], outs[1])  # LUT bit-identical
-    np.testing.assert_allclose(outs[2], outs[0], rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(outs[1], outs[0], rtol=1e-6, atol=1e-6)
 
 
 def test_systematic_shrinkage():

@@ -1,4 +1,9 @@
 """Energy / cycle / area model tests (paper Fig 7-9 + headline claims)."""
+import importlib.util
+import pathlib
+
+import pytest
+
 from repro.core import Variant
 from repro.core import arch_model as A
 from repro.core import energy as E
@@ -67,3 +72,18 @@ def test_capacity_refills():
     small_banks = A.BankConfig(1, 8)
     d = A.daism_cycles(big, small_banks)
     assert d["refills"] > 1
+
+
+@pytest.mark.parametrize("name", ["error_distance", "energy", "arch_cycles"])
+def test_paper_table_claims_hold(name):
+    """Every boolean claim a paper-table module under ``benchmarks/``
+    returns holds (the modules that train models stay out)."""
+    path = pathlib.Path(__file__).parents[1] / "benchmarks" / f"{name}.py"
+    spec = importlib.util.spec_from_file_location(f"paper_table_{name}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    rows, claims = mod.run()
+    assert rows
+    checked = {k: v for k, v in claims.items() if isinstance(v, bool)}
+    assert checked, claims
+    assert all(checked.values()), {k for k, v in checked.items() if not v}

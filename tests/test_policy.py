@@ -47,15 +47,16 @@ def test_kind_pattern_and_kind_restriction():
 
 
 def test_parse_policy_spec():
-    pol = P.parse_policy("*/attn/*=exact,*/ffn/*=pc3_tr:lut,*=fla")
+    pol = P.parse_policy("*/attn/*=exact,*/ffn/*=pc3_tr:pallas,*=fla")
     assert pol.resolve("x/attn/wq").exact
     ffn = pol.resolve("x/ffn/wi")
-    assert ffn.variant is Variant.PC3_TR and ffn.backend is Backend.LUT
+    assert ffn.variant is Variant.PC3_TR and ffn.backend is Backend.PALLAS
     assert pol.resolve("anything/else").variant is Variant.FLA
     with pytest.raises(ValueError, match="unknown variant"):
         P.parse_policy("*=bogus")
-    with pytest.raises(ValueError, match="unknown backend"):
-        P.parse_policy("*=fla:bogus")
+    for backend in ("bogus", "lut"):
+        with pytest.raises(ValueError, match="unknown backend"):
+            P.parse_policy(f"*=fla:{backend}")
     with pytest.raises(ValueError, match="pattern=variant"):
         P.parse_policy("justapattern")
 
@@ -156,9 +157,9 @@ def test_daism_config_construction_validation():
 
 def test_backend_dtype_validation_at_arch_construction():
     cfg = get_config("lenet5")  # float32 compute
-    lut = DaismConfig(variant=Variant.PC3_TR, backend=Backend.LUT)
+    pallas = DaismConfig(variant=Variant.PC3_TR, backend=Backend.PALLAS)
     with pytest.raises(ValueError, match="bfloat16-only"):
-        dataclasses.replace(cfg, daism=lut)
+        dataclasses.replace(cfg, daism=pallas)
     with pytest.raises(ValueError, match="bfloat16-only"):
         cfg.with_policy("cnn/c1=pc3_tr:pallas")
     # jnp backend supports float32: must construct fine
@@ -166,11 +167,11 @@ def test_backend_dtype_validation_at_arch_construction():
 
 
 def test_validate_for_dtype_names_site():
-    lut = DaismConfig(variant=Variant.PC3_TR, backend=Backend.LUT)
+    pallas = DaismConfig(variant=Variant.PC3_TR, backend=Backend.PALLAS)
     with pytest.raises(ValueError, match="decoder/layer_0/attn/wq"):
-        P.validate_for_dtype(lut, jnp.float32,
+        P.validate_for_dtype(pallas, jnp.float32,
                              site="decoder/layer_0/attn/wq")
-    P.validate_for_dtype(lut, jnp.bfloat16)  # ok
+    P.validate_for_dtype(pallas, jnp.bfloat16)  # ok
     P.validate_for_dtype(P.EXACT, jnp.int8)  # exact: anything goes
 
 

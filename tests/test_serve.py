@@ -358,7 +358,7 @@ def test_scheduler_priority_admission_and_requeue():
 
 def test_report_schema_latency_percentiles_and_idle(served):
     """Satellite: ServeReport's percentile/async/preemption fields are
-    schema-stable — downstream (launch/serve.py, serve_bench.py) reads them
+    schema-stable — downstream (launch/serve.py) reads them
     by name."""
     cfg, model, params = served
     engine = ServeEngine(model, params, EngineConfig(
